@@ -9,7 +9,12 @@ claim at this tier: lazy mode scores strictly fewer rows per iteration than
 the exhaustive Θ(m) baseline, at less collective wire on a model-sharded
 mesh.
 
-Rows: ``distributed/d{N}/{mode}`` with per-iteration execution µs;
+The children are CPU processes (``JAX_PLATFORMS=cpu`` is set for them
+explicitly, so none reaches for a chip the parent may hold); the rows
+name the platform they ran on.
+
+Rows: ``distributed/cpu/d{N}/{mode}`` with per-iteration execution µs on
+forced CPU host devices;
 derived packs ``rows=<scored rows/iter>;wire=<collective bytes/iter>;
 sublinear=<lazy rows < exhaustive rows>``.
 """
@@ -34,13 +39,13 @@ _SCRIPT = """
                                         shard_selection_params)
     from repro.core.queries import gaussian_histogram, random_binary_queries
     from repro.mips import ShardedIVFIndex
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.analysis.hlo import analyze_hlo
 
     d, m, U, T = {devices}, {m}, {U}, {T}
     model = 2 if d >= 2 else 1
     n_data = d // model
-    mesh = make_mesh_compat((n_data, model), ("data", "model"))
+    mesh = make_mesh((n_data, model), ("data", "model"))
     key = jax.random.PRNGKey(0)
     kh, kq = jax.random.split(key)
     n_records = 3000
@@ -88,6 +93,7 @@ _SCRIPT = """
 def _probe(devices: int, m: int, U: int, T: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _REPO_SRC
     script = textwrap.dedent(_SCRIPT.format(devices=devices, m=m, U=U, T=T))
     out = subprocess.run([sys.executable, "-c", script], capture_output=True,
@@ -106,7 +112,7 @@ def run(quick: bool = True):
         sublinear = r["lazy"]["rows"] < r["exhaustive"]["rows"]
         for mode in ("exhaustive", "lazy"):
             rows.append(row(
-                f"distributed/d{devices}/{mode}", r[mode]["iter_us"],
+                f"distributed/cpu/d{devices}/{mode}", r[mode]["iter_us"],
                 f"rows={r[mode]['rows']:.0f};wire={r[mode]['wire']:.0f};"
                 f"sublinear={sublinear}"))
     return rows

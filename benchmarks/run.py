@@ -28,6 +28,9 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_distributed, bench_error_parity,
                             bench_ivf_probe, bench_linear_queries, bench_lp,
                             bench_margin, bench_marginals, bench_mwem_step,

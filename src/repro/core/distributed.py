@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.accountant import PrivacyLedger
 from repro.core.gumbel import tail_prob, truncated_gumbel
@@ -68,9 +67,11 @@ from repro.core.mwem import (
 )
 from repro.core.queries import max_error
 from repro.kernels.mwem_step.ops import mwem_step_supported, mwu_apply
+from repro.kernels.route import default_interpret
 from repro.obs.clock import perf_counter
 from repro.obs.telemetry import aggregate_traces, record_run
 from repro.obs.trace import annotate as obs_annotate
+from repro.precision import dot as _dot
 
 
 def _fold_axes(key, axes):
@@ -135,7 +136,7 @@ def _make_iteration_body(mesh, *, m: int, U: int, nlist: int, cap: int,
         keyed by k_sel (each shard slices its segment), so the mechanism is
         bitwise the host `_exact_argmax`. Output padded to the lazy
         candidate buffer so both `lax.cond` branches agree on shapes."""
-        scores = jax.lax.psum(Q @ v, "model")              # (m_loc,)
+        scores = jax.lax.psum(_dot(Q, v), "model")         # (m_loc,)
         x = jnp.abs(scores) * scale
         g_full = jax.random.gumbel(k_sel, (m,))
         g = jax.lax.dynamic_slice(g_full, (shard_id * m_loc,), (m_loc,))
@@ -167,12 +168,12 @@ def _make_iteration_body(mesh, *, m: int, U: int, nlist: int, cap: int,
             n_probe_scored = jnp.float32(nlist) + n_valid.astype(jnp.float32)
         else:
             # ---- IVF pruning: pick nprobe cells by centroid score ----
-            cscores = jax.lax.psum(cents[0] @ v, "model")  # (nlist,)
+            cscores = jax.lax.psum(_dot(cents[0], v), "model")  # (nlist,)
             _, probe = jax.lax.top_k(jnp.abs(cscores), nprobe)
             cand = cells[0][probe].reshape(-1)             # (nprobe·cap,)
             valid = cand >= 0
             rows = Q[jnp.clip(cand, 0)]                    # (cand, U_loc)
-            cscore = jax.lax.psum(rows @ v, "model")
+            cscore = jax.lax.psum(_dot(rows, v), "model")
             x_cand = jnp.where(valid, jnp.abs(cscore) * scale, -jnp.inf)
             top_x, top_pos = jax.lax.top_k(x_cand, k_loc)
             top_ids = cand[top_pos]
@@ -205,7 +206,7 @@ def _make_iteration_body(mesh, *, m: int, U: int, nlist: int, cap: int,
             kt, safe_top, m_loc, tail_cap, C)
         tail_ids = jnp.clip(tail_ids, 0, m_loc - 1)
         trows = Q[tail_ids]
-        tscore = jax.lax.psum(trows @ v, "model")
+        tscore = jax.lax.psum(_dot(trows, v), "model")
         tx = jnp.abs(tscore) * scale
         tg = truncated_gumbel(kg2, (tail_cap,), B)
         pert_tail = jnp.where(active, tx + tg, -jnp.inf)
@@ -291,10 +292,10 @@ def _make_iteration_body(mesh, *, m: int, U: int, nlist: int, cap: int,
         if rule == "paper":
             logw_new = logw - eta * row
         else:
-            true_ans = jax.lax.psum(jnp.dot(row, h), "model")
+            true_ans = jax.lax.psum(_dot(row, h), "model")
             noise = lap_scale * jax.random.laplace(k_meas)
             measured = true_ans + noise
-            est = jax.lax.psum(jnp.dot(row, p), "model")
+            est = jax.lax.psum(_dot(row, p), "model")
             if rule == "signed":
                 logw_new = logw + eta * jnp.sign(measured - est) * row
             elif rule == "hardt":
@@ -361,11 +362,11 @@ def make_mwem_iteration(mesh, *, m: int, U: int, nlist: int, cap: int,
                                   _raw_key(k_sel), _raw_key(k_meas))
         return logw_new, stats
 
-    return shard_map(
+    return jax.shard_map(
         iteration, mesh=mesh,
         in_specs=(q_spec, cent_spec, cell_spec, w_spec, w_spec, P()),
         out_specs=(w_spec, _STAT_SPECS),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -414,7 +415,7 @@ def make_mwem_scan(mesh, *, T: int, m: int, U: int, nlist: int, cap: int,
                 # eval schedule, mirroring the fused driver
                 def _err(_):
                     v_err = h - p_sum2 / t.astype(jnp.float32)
-                    s = jax.lax.psum(Q @ v_err, "model")
+                    s = jax.lax.psum(_dot(Q, v_err), "model")
                     return jax.lax.pmax(jnp.max(jnp.abs(s)), data_axes)
 
                 stats = dict(stats, error=jax.lax.cond(
@@ -430,12 +431,12 @@ def make_mwem_scan(mesh, *, T: int, m: int, U: int, nlist: int, cap: int,
     stat_specs = dict(_STAT_SPECS)
     if eval_every:
         stat_specs["error"] = P()
-    return shard_map(
+    return jax.shard_map(
         scan_fn, mesh=mesh,
         in_specs=(q_spec, cent_spec, cell_spec, w_spec, w_spec, w_spec,
                   P(), P()),
         out_specs=(w_spec, w_spec, stat_specs),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -536,14 +537,7 @@ def run_mwem_sharded(
         k_loc, tail_cap = shard_selection_params(m_loc, index,
                                                  k=cfg.k,
                                                  tail_cap=cfg.tail_cap)
-        # the fused probe kernel replaces the gather→matvec→top_k only when
-        # "model" has extent 1 (it fuses dot+top-k, so the partial-dot psum
-        # of a model-sharded probe cannot interpose) — automatic fallback
-        # to the XLA probe otherwise
-        try:
-            use_pallas = index._resolve_pallas() and n_model == 1
-        except AttributeError:
-            use_pallas = False
+        use_pallas = index.kernel_probe(n_model)
     else:
         # dummy per-shard structure: the exhaustive body never reads it
         cents = jnp.zeros((n_data, 1, U), jnp.float32)
@@ -558,7 +552,7 @@ def run_mwem_sharded(
                    multi_pod="pod" in mesh.axis_names,
                    eval_every=cfg.eval_every,
                    use_pallas=use_pallas,
-                   interpret=jax.default_backend() != "tpu")
+                   interpret=default_interpret())
     entry = _jitted_scan(mesh, statics)
 
     # device_put is a no-op for arrays already placed with the target

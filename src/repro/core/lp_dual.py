@@ -40,6 +40,7 @@ from repro.core.lp_scalar import (ScalarLPConfig, _check_lp_fast_index,
                                   _lp_fused_driver, _record_lp_iteration,
                                   _resolve_lp_driver, lp_split_chain,
                                   scalar_lp_release_cost)
+from repro.mips.base import index_state
 from repro.obs.clock import perf_counter
 from repro.obs.telemetry import MechanismTelemetry, record_run
 from repro.obs.trace import annotate as obs_annotate
@@ -261,7 +262,8 @@ def solve_constraint_private_lp_fused(
     N = -(opt / c)[:, None] * A.T          # (d, m): N_j as rows
     entry = _lp_fused_driver(index if cfg.mode == "fast" else None,
                              _dual_core, _dual_statics(cfg, cal, opt), "dual")
-    args = (A, b, c, N, key)
+    args = (A, b, c, N, key,
+            index_state(index if cfg.mode == "fast" else None))
     driver = _compiled_driver(entry, *args)
     t0 = perf_counter()
     with obs_annotate("lp_dual/fused"):

@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
@@ -48,6 +47,7 @@ from repro.core.accountant import PrivacyLedger, calibrate_eps0
 from repro.core.gumbel import gumbel
 from repro.core.lazy_em import (default_tail_cap, fallback_key,
                                 lazy_em_from_topk)
+from repro.mips.base import bind_state, index_state
 from repro.obs.clock import perf_counter
 from repro.obs.telemetry import MechanismTelemetry, record_run
 from repro.obs.trace import annotate as obs_annotate
@@ -285,17 +285,24 @@ def _lp_fused_driver(index, core, statics: dict, tag: str,
     """Build (or fetch) the jitted fused LP driver for an (index, config)
     pair — the LP counterpart of `mwem._fused_driver`. Compiled drivers are
     cached on the index instance (module-level for ``mode="exact"``);
-    ``batch_axes`` is a vmap ``in_axes`` tuple for the batched driver."""
+    ``batch_axes`` is a vmap ``in_axes`` tuple for the batched driver. The
+    driver's last argument is ``index_state(index)``."""
     cache = (_LP_EXACT_DRIVER_CACHE if index is None
              else index.__dict__.setdefault("_lp_fused_driver_cache", {}))
     ck = (tag, tuple(sorted(statics.items())), batch_axes,
           getattr(index, "_use_pallas", None))
     entry = cache.get(ck)
     if entry is None:
-        query_fn = index.query_in_graph if index is not None else None
-        fn = partial(core, query_fn=query_fn, **statics)
+        # the index's tables are the driver's last argument, never
+        # constants captured from the closure
+        def fn(*args):
+            *arrays, state = args
+            bound = bind_state(index, state)
+            return core(*arrays, query_fn=None if bound is None
+                        else bound.query_in_graph, **statics)
+
         if batch_axes is not None:
-            fn = jax.vmap(fn, in_axes=batch_axes)
+            fn = jax.vmap(fn, in_axes=batch_axes + (None,))
         entry = (jax.jit(fn), {})
         cache[ck] = entry
     return entry
@@ -338,7 +345,7 @@ def solve_scalar_lp_fused(
 
     entry = _lp_fused_driver(index if cfg.mode == "fast" else None,
                              _scalar_core, _scalar_statics(cfg, cal), "scalar")
-    args = (A, b, key)
+    args = (A, b, key, index_state(index if cfg.mode == "fast" else None))
     driver = _compiled_driver(entry, *args)
     t0 = perf_counter()
     with obs_annotate("lp_scalar/fused"):
@@ -413,7 +420,7 @@ def launch_lp_batch(
     entry = _lp_fused_driver(index if cfg.mode == "fast" else None,
                              _scalar_core, _scalar_statics(cfg, cal), "scalar",
                              batch_axes=(None, 0 if batched_b else None, 0))
-    args = (A, b, keys)
+    args = (A, b, keys, index_state(index if cfg.mode == "fast" else None))
     driver = _compiled_driver(entry, *args)
     t0 = perf_counter()
     with obs_annotate("lp_scalar/batch"):
@@ -492,7 +499,8 @@ def aot_compile_lp_batch(A, b, cfg: ScalarLPConfig, lanes: int,
                              batch_axes=(None, None, 0))
     keys = jnp.stack([jax.random.PRNGKey(0)] * lanes)
     n_before = len(entry[1])
-    _compiled_driver(entry, A, b, keys)
+    _compiled_driver(entry, A, b, keys,
+                     index_state(index if cfg.mode == "fast" else None))
     return len(entry[1]) > n_before
 
 

@@ -69,7 +69,8 @@ from repro.core.queries import max_error
 from repro.core.workload import Workload, as_workload
 from repro.kernels.mwem_step import ops as step_ops
 from repro.kernels.mwem_step.ref import mwem_step_ref, mwu_apply_ref
-from repro.mips.base import resolve_pallas
+from repro.mips.base import bind_state, index_state, resolve_pallas
+from repro.precision import dot as _dot
 
 
 @dataclass(frozen=True)
@@ -90,7 +91,7 @@ class MWEMConfig:
     # Megakernel knob for the fused/sharded scans (mips.base semantics):
     # "auto"/"always" run the carried-density mega step — Pallas kernel when
     # resolve_pallas says so AND the shape qualifies, else the XLA ref, both
-    # bitwise the host math; "never" keeps the classic pre-fusion body (the
+    # the host math; "never" keeps the classic pre-fusion body (the
     # roofline baseline).
     use_pallas: str = "auto"
 
@@ -401,12 +402,7 @@ def _fused_core(W: Workload, h: jax.Array, state0: MWEMState, key: jax.Array,
                 fallback_key(k_sel), jnp.abs(s_full) * scale)
         else:
             aug_idx, raw = query_fn(v, k)
-            if kernel and W.is_dense:
-                # tail candidates stream once via the scalar-prefetched
-                # gather-score kernel (bitwise `_aug_score` — per-row dot)
-                score_fn = lambda idx: (  # noqa: E731
-                    step_ops.aug_gather_score(W.Q, v, idx) * scale)
-            elif kernel:
+            if kernel and not W.is_dense:
                 # factored row fetch: offsets + implicit one-hot products,
                 # no (m, U) gather anywhere
                 score_fn = lambda idx: (  # noqa: E731
@@ -627,7 +623,8 @@ def _fused_driver(index, statics: dict, batch_axes=None) -> Callable:
     ``mode="exact"``) so repeated runs with the same shapes re-dispatch the
     cached executable. The carried `MWEMState` buffers are donated.
     ``batch_axes`` is a vmap ``in_axes`` tuple over (Q, h, state0, key) for
-    the batched driver, or None for the single-run driver.
+    the batched driver, or None for the single-run driver. The driver's
+    arguments are ``(Q, h, state0, key, index_state(index))``.
     """
     cache = (_EXACT_DRIVER_CACHE if index is None
              else index.__dict__.setdefault("_fused_driver_cache", {}))
@@ -638,20 +635,30 @@ def _fused_driver(index, statics: dict, batch_axes=None) -> Callable:
           getattr(index, "_use_pallas", None))
     entry = cache.get(ck)
     if entry is None:
+        # the index's tables are the driver's last argument (`index_state`),
+        # never constants captured from the closure
         if waved:
-            core = partial(_fused_core_waved,
-                           batch_query_fn=index.query_in_graph_batch,
-                           **statics)
+            def core(W, h, state0, keys, state):
+                return _fused_core_waved(
+                    W, h, state0, keys,
+                    batch_query_fn=bind_state(index, state)
+                    .query_in_graph_batch, **statics)
         else:
-            query_fn = None
-            if getattr(index, "has_full_scores", False):
-                query_fn = index.query_in_graph_with_scores
+            full = getattr(index, "has_full_scores", False)
+            if full:
                 statics = dict(statics, query_returns_scores=True)
-            elif index is not None:
-                query_fn = index.query_in_graph
-            core = partial(_fused_core, query_fn=query_fn, **statics)
+
+            def per_lane(W, h, state0, key, state):
+                bound = bind_state(index, state)
+                query_fn = (None if bound is None
+                            else bound.query_in_graph_with_scores if full
+                            else bound.query_in_graph)
+                return _fused_core(W, h, state0, key, query_fn=query_fn,
+                                   **statics)
+
+            core = per_lane
             if batch_axes is not None:
-                core = jax.vmap(core, in_axes=batch_axes)
+                core = jax.vmap(core, in_axes=batch_axes + (None,))
         entry = (jax.jit(core, donate_argnums=(2,)), {})
         cache[ck] = entry
     return entry
@@ -727,7 +734,8 @@ def run_mwem_fused(
                           _fused_statics(cfg, cal))
     state0 = MWEMState(log_w=jnp.zeros((U,), jnp.float32),
                        p_sum=jnp.zeros((U,), jnp.float32))
-    args = (W, jnp.asarray(h, jnp.float32), state0, key)
+    args = (W, jnp.asarray(h, jnp.float32), state0, key,
+            index_state(index if cfg.mode == "fast" else None))
     driver = _compiled_driver(entry, *args)
     t0 = perf_counter()
     with obs_annotate("mwem/fused"):
@@ -822,7 +830,8 @@ def launch_mwem_batch(
                     else "fused")
     state0 = MWEMState(log_w=jnp.zeros((B, U), jnp.float32),
                        p_sum=jnp.zeros((B, U), jnp.float32))
-    args = (W, h, state0, keys)
+    args = (W, h, state0, keys,
+            index_state(index if cfg.mode == "fast" else None))
     driver = _compiled_driver(entry, *args)
     t0 = perf_counter()
     with obs_annotate(f"mwem/batch/{driver_label}"):
@@ -852,7 +861,7 @@ def finish_mwem_batch(pending: MWEMPendingBatch,
 
     p_hat = final_state.p_sum / cfg.T
     if W.is_dense:  # pre-refactor expression, kept bitwise
-        final_errors = jnp.max(jnp.abs((h - p_hat) @ W.Q.T), axis=-1)
+        final_errors = jnp.max(jnp.abs(_dot(h - p_hat, W.Q.T)), axis=-1)
     else:
         final_errors = jax.vmap(
             lambda hh, pp: max_error(W, hh, pp),
@@ -924,7 +933,8 @@ def aot_compile_batch(Q, cfg: MWEMConfig, lanes: int, index=None,
     state0 = MWEMState(log_w=jnp.zeros((lanes, U), jnp.float32),
                        p_sum=jnp.zeros((lanes, U), jnp.float32))
     n_before = len(entry[1])
-    _compiled_driver(entry, W, h, state0, keys)
+    _compiled_driver(entry, W, h, state0, keys,
+                     index_state(index if cfg.mode == "fast" else None))
     return len(entry[1]) > n_before
 
 

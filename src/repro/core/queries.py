@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.precision import dot as _dot
+
 
 def gaussian_histogram(key: jax.Array, n: int, U: int, mean=None, std=None) -> jax.Array:
     """§5.1 dataset: n points from N(U/3, U/15) binned into [0, U)."""
@@ -71,7 +73,7 @@ def max_error(Q, h: jax.Array, p: jax.Array) -> jax.Array:
     """
     if hasattr(Q, "max_err"):
         return Q.max_err(h, p)
-    return jnp.max(jnp.abs(Q @ (p - h)))
+    return jnp.max(jnp.abs(_dot(Q, p - h)))
 
 
 def random_feasible_lp(key: jax.Array, m: int, d: int, slack: float = 0.1):

@@ -40,6 +40,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.precision import dot as _dot
+
+
 __all__ = [
     "Workload", "DenseWorkload", "MarginalWorkload", "as_workload",
     "aug_decompose",
@@ -107,7 +110,7 @@ class Workload:
         ``sign_j · ⟨q_{j % m}, v⟩``. Same op shape as the dense tail gather
         (`(t, U) @ (U,)`), so bitwise with `core.mwem._aug_score`."""
         base, sign = aug_decompose(jnp.asarray(aug_ids), self.m)
-        return (self.rows(base) @ v) * sign
+        return _dot(self.rows(base), v) * sign
 
     def max_err(self, h, p) -> jax.Array:
         """‖Q(p − h)‖_∞ without densification (Eq. 1)."""
@@ -159,21 +162,26 @@ class DenseWorkload(Workload):
         return self.Q[jnp.asarray(ids)]
 
     def scores(self, v) -> jax.Array:
-        return self.Q @ v
+        return _dot(self.Q, v)
 
     def answer_all(self, v) -> jax.Array:
-        return self.Q @ v
+        return _dot(self.Q, v)
 
     def max_err(self, h, p) -> jax.Array:
         # verbatim queries.max_error — keeps the dense path bitwise
-        return jnp.max(jnp.abs(self.Q @ (p - h)))
+        return jnp.max(jnp.abs(_dot(self.Q, p - h)))
 
     def score_in_graph(self, v, aug_ids) -> jax.Array:
         base, sign = aug_decompose(jnp.asarray(aug_ids), self.m)
-        return (self.Q[base] @ v) * sign
+        return _dot(self.Q[base], v) * sign
 
     def densify(self, limit: int = _DENSIFY_LIMIT_BYTES) -> np.ndarray:
         return np.asarray(self.Q, np.float32)
+
+    def require_dense(self, context: str,
+                      limit: int = _DENSIFY_LIMIT_BYTES) -> jnp.ndarray:
+        """The table itself, where it already lives (no host round trip)."""
+        return self.Q
 
     def tree_flatten(self):
         # aux must not read the leaf: jax round-trips pytrees with
@@ -315,10 +323,10 @@ class MarginalWorkload(Workload):
         bitwise; larger workloads chunk (reassociation accepted there)."""
         B = self.score_block
         if self.m <= B:
-            return self.rows(jnp.arange(self.m)) @ v
+            return _dot(self.rows(jnp.arange(self.m)), v)
         nb = -(-self.m // B)
         ids = jnp.clip(jnp.arange(nb * B), 0, self.m - 1)
-        out = [self.rows(ids[b * B:(b + 1) * B]) @ v for b in range(nb)]
+        out = [_dot(self.rows(ids[b * B:(b + 1) * B]), v) for b in range(nb)]
         return jnp.concatenate(out)[:self.m]
 
     def marginal_tables(self, v) -> jax.Array:

@@ -4,7 +4,7 @@ The Θ(√m) selection step of Fast-MWEM is an IVF probe: score the nlist
 centroids, pick the top-nprobe cells, score only those cells' rows, keep
 the top-k. The XLA lowering materializes the gathered (nprobe·cap, dim)
 candidate matrix in HBM (gather out, matvec back in — the rows cross the
-HBM bus three times). These kernels never materialize it:
+HBM bus three times). This kernel never materializes it:
 
 * rows live in HBM pre-grouped by cell (``cell_rows`` (nlist, cap, dim),
   built once per index);
@@ -12,213 +12,79 @@ HBM bus three times). These kernels never materialize it:
   (`pltpu.PrefetchScalarGridSpec`), so the Pallas pipeline's index_map
   reads them before the body runs and DMAs exactly the probed cells'
   (cap, block_d) tiles HBM→VMEM, double-buffered across grid steps;
-* partial dots accumulate in a VMEM scratch across the d-tiles and merge
-  into a running top-k scratch — only the (k,) result leaves the chip.
+* partial dots accumulate in the resident (slots, B, cap) score block;
+  only those scores — 1/dim of the streamed bytes — leave the chip, and
+  the masking and top-k run in XLA (`ops.py`: Mosaic has no in-kernel
+  `top_k`).
 
-Bytes touched: nlist·dim (centroids, scored by the `mips_topk` streaming
-kernel) + nprobe·cap·dim (probed rows, once) — vs the XLA path's
-~3× nprobe·cap·dim gather traffic (`analysis.roofline.ivf_probe_roofline`).
+Bytes touched: nlist·dim (centroids) + nprobe·cap·dim (probed rows, once)
+— vs the XLA path's ~3× nprobe·cap·dim gather traffic
+(`analysis.roofline.ivf_probe_roofline`).
 
-The batched kernel amortizes the stream across a serve wave of B probes:
-the union of all lanes' probed cells is deduplicated — the unique cells
-stream first (each read from HBM once however many lanes probed it) and
-the fully-masked duplicate tail repeats the last unique id, revisiting the
-block already resident in VMEM rather than re-streaming distinct cells.
-Every streamed (cap, block_d) tile feeds one (cap × block_d) @
-(block_d × B) MXU matmul — the wave turns gather-bound probing into
-MXU-bound matmuls (scoring runs for all B lanes per tile; a per-slot
-membership mask blanks lanes that did not probe the cell after the
-matmul — the dedup shares reads, not FLOPs).
+One kernel serves a single probe (B = 1, slots = the probed cells) and a
+serve wave of B probes. For a wave the union of all lanes' probed cells is
+deduplicated: the unique cells stream first and the duplicate tail repeats
+the last unique id. The grid runs d-tiles outermost, so consecutive
+duplicate slots ask for the same block and the pipeline keeps the resident
+one instead of streaming it again. Every streamed (cap, block_d) tile feeds
+one (B × block_d) · (cap × block_d)ᵀ MXU contraction — the wave turns
+gather-bound probing into matmuls (scoring runs for all B lanes per tile;
+ops.py masks the lanes that did not probe a cell — the dedup shares reads,
+not FLOPs). Contractions run at f32 precision (``Precision.HIGHEST``).
 
-Grids: single (nprobe, d_tiles), batched (n_slots, d_tiles), d innermost.
-All shapes padded by ops.py.
+Grid: (d_tiles, n_slots). ``block_d`` divides dim (ops.py picks it).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _stream_kernel(probe_ref, rows_ref, ids_ref, q_ref, out_i_ref, out_s_ref,
-                   acc_ref, top_s_ref, top_i_ref, *, k: int, absolute: bool):
-    del probe_ref  # consumed by the index_maps, not the body
-    ci = pl.program_id(0)
-    di = pl.program_id(1)
-    nd = pl.num_programs(1)
-
-    @pl.when(di == 0)
-    def _init_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # (cap, block_d) @ (block_d,) partial dots for this cell, f32 accum.
-    acc_ref[...] += rows_ref[0].astype(jnp.float32) @ q_ref[...].astype(jnp.float32)
-
-    @pl.when(di == nd - 1)
-    def _merge():
-        @pl.when(ci == 0)
-        def _init_top():
-            top_s_ref[...] = jnp.full_like(top_s_ref, -jnp.inf)
-            top_i_ref[...] = jnp.full_like(top_i_ref, -1)
-
-        ids = ids_ref[0]                       # (cap,) row ids, -1 = padding
-        acc = acc_ref[...]
-        scores = jnp.abs(acc) if absolute else acc
-        scores = jnp.where(ids >= 0, scores, -jnp.inf)
-        # Stable merge: the running buffer (earlier cells) sits first in the
-        # concat, so incremental top-k equals one `lax.top_k` over the flat
-        # candidate vector in probe order — ties break identically to ref.py.
-        merged_s = jnp.concatenate([top_s_ref[...], scores])
-        merged_i = jnp.concatenate([top_i_ref[...], ids])
-        new_s, pos = jax.lax.top_k(merged_s, k)
-        top_s_ref[...] = new_s
-        top_i_ref[...] = merged_i[pos]
-
-        @pl.when(ci == pl.num_programs(0) - 1)
-        def _emit():
-            out_s_ref[...] = top_s_ref[...]
-            out_i_ref[...] = top_i_ref[...]
+from repro.kernels.mips_topk.mips_topk import row_scores
 
 
-def ivf_probe_stream_pallas(probe: jax.Array, rows_p: jax.Array,
-                            ids_p: jax.Array, qp: jax.Array, k: int, *,
-                            block_d: int, interpret: bool, absolute: bool):
-    """Padded-shape pallas_call; use ops.ivf_probe_topk for the public API.
+def _kernel(slots_ref, rows_ref, qb_ref, out_ref):
+    del slots_ref  # consumed by the index_maps, not the body
+    s = pl.program_id(1)
 
-    ``probe`` (nprobe,) int32 cell ids is the scalar-prefetch operand: the
-    index_maps read ``probe[ci]`` to pick which HBM cell block the pipeline
-    DMAs next, so un-probed cells are never touched.
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        out_ref[s] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
+
+    # (B, block_d) · (cap, block_d)ᵀ → (B, cap) partial dots for this cell
+    out_ref[s] += row_scores(qb_ref[...], rows_ref[0])
+
+
+def ivf_scores_pallas(slots: jax.Array, rows_p: jax.Array, qb: jax.Array, *,
+                      block_d: int, interpret: bool) -> jax.Array:
+    """``(n_slots, B, cap)`` f32 scores of the probe rows ``qb`` (B, dim)
+    against the rows of cell ``slots[s]`` of ``rows_p`` (nlist, cap, dim).
+
+    ``slots`` (n_slots,) int32 is the scalar-prefetch operand: the
+    index_map reads ``slots[s]`` to pick which HBM cell block the pipeline
+    DMAs next, so cells that no slot names are never touched.
     """
-    nlist, cap, dp = rows_p.shape
-    nprobe = probe.shape[0]
-    assert dp % block_d == 0 and qp.shape[0] == dp, "ops.py must pad"
-    grid = (nprobe, dp // block_d)
-    kern = functools.partial(_stream_kernel, k=k, absolute=absolute)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, cap, block_d),
-                         lambda i, j, probe_ref: (probe_ref[i], 0, j)),
-            pl.BlockSpec((1, cap), lambda i, j, probe_ref: (probe_ref[i], 0)),
-            pl.BlockSpec((block_d,), lambda i, j, probe_ref: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((k,), lambda i, j, probe_ref: (0,)),
-            pl.BlockSpec((k,), lambda i, j, probe_ref: (0,)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((cap,), jnp.float32),
-            pltpu.VMEM((k,), jnp.float32),
-            pltpu.VMEM((k,), jnp.int32),
-        ],
-    )
-    out_i, out_s = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((k,), jnp.int32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(probe, rows_p, ids_p, qp)
-    return out_i, out_s
-
-
-def _stream_batch_kernel(slots_ref, rows_ref, ids_ref, qb_ref, member_ref,
-                         out_i_ref, out_s_ref, acc_ref, top_s_ref, top_i_ref,
-                         *, k: int, absolute: bool):
-    del slots_ref
-    si = pl.program_id(0)
-    di = pl.program_id(1)
-    nd = pl.num_programs(1)
-    B = top_s_ref.shape[0]
-    cap = ids_ref.shape[1]
-
-    @pl.when(di == 0)
-    def _init_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # One MXU matmul scores this cell tile against the whole wave:
-    # (cap, block_d) @ (block_d, B) → (cap, B).
-    acc_ref[...] += jnp.dot(rows_ref[0].astype(jnp.float32),
-                            qb_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(di == nd - 1)
-    def _merge():
-        @pl.when(si == 0)
-        def _init_top():
-            top_s_ref[...] = jnp.full_like(top_s_ref, -jnp.inf)
-            top_i_ref[...] = jnp.full_like(top_i_ref, -1)
-
-        ids = ids_ref[0]                       # (cap,)
-        member = member_ref[0]                 # (B,) 1.0 iff lane probed cell
-        acc = acc_ref[...]                     # (cap, B)
-        scores = jnp.abs(acc) if absolute else acc
-        scores_t = scores.T                    # (B, cap)
-        mask = (ids[None, :] >= 0) & (member[:, None] > 0)
-        scores_t = jnp.where(mask, scores_t, -jnp.inf)
-        ids_b = jnp.broadcast_to(ids[None, :], (B, cap))
-        merged_s = jnp.concatenate([top_s_ref[...], scores_t], axis=1)
-        merged_i = jnp.concatenate([top_i_ref[...], ids_b], axis=1)
-        new_s, pos = jax.lax.top_k(merged_s, k)
-        top_s_ref[...] = new_s
-        top_i_ref[...] = jnp.take_along_axis(merged_i, pos, axis=1)
-
-        @pl.when(si == pl.num_programs(0) - 1)
-        def _emit():
-            out_s_ref[...] = top_s_ref[...]
-            out_i_ref[...] = top_i_ref[...]
-
-
-def ivf_probe_stream_batch_pallas(slots: jax.Array, rows_p: jax.Array,
-                                  ids_p: jax.Array, qbp: jax.Array,
-                                  member: jax.Array, k: int, *, block_d: int,
-                                  interpret: bool, absolute: bool):
-    """Batched padded-shape pallas_call (ops.ivf_probe_topk_batch public).
-
-    ``slots`` (n_slots,) int32 deduplicated cell ids (scalar-prefetched);
-    ``qbp`` (dp, B) probe vectors as columns; ``member`` (n_slots, B) 0/1
-    lane-membership mask. A cell shared by lanes streams from HBM once.
-    """
-    nlist, cap, dp = rows_p.shape
+    nlist, cap, dim = rows_p.shape
     n_slots = slots.shape[0]
-    B = qbp.shape[1]
-    assert dp % block_d == 0, "ops.py must pad"
-    grid = (n_slots, dp // block_d)
-    kern = functools.partial(_stream_batch_kernel, k=k, absolute=absolute)
+    B = qb.shape[0]
+    assert dim % block_d == 0, "ops.py picks a block_d that divides dim"
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(dim // block_d, n_slots),
         in_specs=[
             pl.BlockSpec((1, cap, block_d),
-                         lambda i, j, slots_ref: (slots_ref[i], 0, j)),
-            pl.BlockSpec((1, cap), lambda i, j, slots_ref: (slots_ref[i], 0)),
-            pl.BlockSpec((block_d, B), lambda i, j, slots_ref: (j, 0)),
-            pl.BlockSpec((1, B), lambda i, j, slots_ref: (i, 0)),
+                         lambda j, s, slots_ref: (slots_ref[s], 0, j)),
+            pl.BlockSpec((B, block_d), lambda j, s, slots_ref: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((B, k), lambda i, j, slots_ref: (0, 0)),
-            pl.BlockSpec((B, k), lambda i, j, slots_ref: (0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((cap, B), jnp.float32),
-            pltpu.VMEM((B, k), jnp.float32),
-            pltpu.VMEM((B, k), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec((n_slots, B, cap),
+                               lambda j, s, slots_ref: (0, 0, 0)),
     )
-    out_i, out_s = pl.pallas_call(
-        kern,
+    return pl.pallas_call(
+        _kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((n_slots, B, cap), jnp.float32),
         interpret=interpret,
-    )(slots, rows_p, ids_p, qbp, member)
-    return out_i, out_s
+        name="ivf_probe_scores",
+    )(slots, rows_p, qb)

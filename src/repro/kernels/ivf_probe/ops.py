@@ -1,18 +1,21 @@
-"""Public jit'd wrappers for the fused IVF probe (padding + dispatch).
+"""Public jit'd wrappers for the fused IVF probe (dispatch + top-k).
 
 Layout contract: callers hold the index rows twice — the flat ``V`` the
 XLA reference gathers from, and ``cell_rows`` (nlist, cap, dim), the same
 rows pre-grouped by cell so a probed cell is one contiguous HBM block the
 kernel's scalar-prefetched index_map can DMA directly (`mips.IVFIndex`
-builds it lazily, only when the Pallas route is live).
+builds it once, only when the Pallas route is live). The kernel reads
+``cell_rows`` as it is: no call pads or copies it.
 
-Stage split: the centroid scoring + top-nprobe runs through the streaming
-`mips_topk` kernel (VMEM-resident, mode="abs" for the sharded driver's
-|·| ordering); its (nprobe,) cell ids feed the stream kernel's scalar
-prefetch with no host round-trip. The batched wrapper plans its probes
-with one XLA (B × dim) @ (dim × nlist) matmul instead — at wave width the
-centroid stage is MXU-bound already, and the dedup/membership planning is
-pure jnp either way.
+Stage split: the single probe scores its centroids through the streaming
+`mips_topk` kernel (mode "abs" for the sharded driver's |·| ordering); its
+(nprobe,) cell ids feed the stream kernel's scalar prefetch with no host
+round-trip. The batched wrapper plans its probes with one XLA
+(B × dim) @ (dim × nlist) matmul instead — at wave width the centroid
+stage is MXU-bound already, and the dedup/membership planning is pure jnp
+either way. Both finish in XLA: mask pad slots (and, for a wave, cells a
+lane did not probe) to −inf, then one `jax.lax.top_k` over the candidates
+in slot order — the order `ref.py` ranks in, so ids and ties agree.
 """
 
 from __future__ import annotations
@@ -22,27 +25,36 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ivf_probe.ivf_probe import (ivf_probe_stream_batch_pallas,
-                                               ivf_probe_stream_pallas)
-from repro.kernels.ivf_probe.ref import batch_probe_slots
-from repro.kernels.mips_topk.ops import _pad_to, mips_topk
+from repro.kernels.ivf_probe.ivf_probe import ivf_scores_pallas
+from repro.kernels.ivf_probe.ref import batch_probe_slots, masked_top_k
+from repro.kernels.mips_topk.ops import mips_topk, pick_block
+from repro.kernels.route import pallas_route
 from repro.obs.trace import scope as obs_scope
 
 
-def _pad_cell_blocks(cell_rows, cells, block_d: int, cap_mult: int = 8):
-    """Pad cap to a sublane multiple (pad slots id −1) and dim to block_d."""
-    rows_p = _pad_to(_pad_to(cell_rows, 1, cap_mult), 2, block_d)
-    pad_cap = rows_p.shape[1] - cells.shape[1]
-    ids_p = cells
-    if pad_cap:
-        ids_p = jnp.pad(cells, ((0, 0), (0, pad_cap)), constant_values=-1)
-    return rows_p, ids_p
+def _cell_ids(cells, probed, cap: int):
+    """Row ids of the probed cells, padded with −1 to ``cell_rows``' cap
+    (`mips.IVFIndex` pads its cell blocks to the sublane multiple)."""
+    ids = cells[probed]
+    pad = cap - cells.shape[1]
+    if pad:
+        ids = jnp.pad(ids, [(0, 0)] * (ids.ndim - 1) + [(0, pad)],
+                      constant_values=-1)
+    return ids
+
+
+def _top_candidates(scores, ids, k: int, absolute: bool):
+    """top-k over (..., n_cand) candidate scores; −1 ids and masked slots
+    (score −inf) never win a finite rank."""
+    if absolute:
+        scores = jnp.abs(scores)
+    return masked_top_k(jnp.where(ids >= 0, scores, -jnp.inf), ids, k)
 
 
 @partial(jax.jit, static_argnames=("k", "nprobe", "block_d", "interpret",
                                    "absolute"))
 def ivf_probe_topk(cents: jax.Array, cell_rows: jax.Array, cells: jax.Array,
-                   q: jax.Array, k: int, nprobe: int, *, block_d: int = 512,
+                   q: jax.Array, k: int, nprobe: int, *, block_d: int = 2048,
                    interpret: bool | None = None, absolute: bool = False):
     """Fused IVF probe: top-k inner products over the top-``nprobe`` cells.
 
@@ -55,53 +67,52 @@ def ivf_probe_topk(cents: jax.Array, cell_rows: jax.Array, cells: jax.Array,
         absolute scores (the sharded driver's ordering); False matches
         `mips.IVFIndex`'s signed ordering.
 
-    Returns ``(idx (k,) int32, scores (k,) f32, n_valid () int32)`` —
-    bitwise `ref.ivf_probe_topk_ref` (same candidate order, stable merge),
-    with ``idx = −1`` beyond the valid candidates.
+    Returns ``(idx (k,) int32, scores (k,) f32, n_valid () int32)`` — the
+    `ref.ivf_probe_topk_ref` candidates in the same order, with
+    ``idx = −1`` beyond the valid candidates.
     """
     nlist, cap, dim = cell_rows.shape
-    block_d = min(block_d, max(8, dim))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     probe, _ = mips_topk(cents, q, nprobe, block_d=block_d,
                          interpret=interpret, absolute=absolute)
-    rows_p, ids_p = _pad_cell_blocks(cell_rows, cells, block_d)
-    qp = _pad_to(q, 0, block_d)
+    interpret = pallas_route("ivf_probe", interpret)
     with obs_scope("kernel/ivf_probe"):
-        out_i, out_s = ivf_probe_stream_pallas(
-            probe, rows_p, ids_p, qp, k, block_d=block_d, interpret=interpret,
-            absolute=absolute)
-    n_valid = jnp.sum(cells[probe] >= 0).astype(jnp.int32)
-    return out_i, out_s, n_valid
+        s = ivf_scores_pallas(probe, cell_rows, q.reshape(1, dim),
+                              block_d=pick_block(dim, block_d),
+                              interpret=interpret)       # (nprobe, 1, cap)
+    ids = _cell_ids(cells, probe, cap)                   # (nprobe, cap)
+    out_i, out_s = _top_candidates(s[:, 0].reshape(-1), ids.reshape(-1), k,
+                                   absolute)
+    return out_i, out_s, jnp.sum(ids >= 0).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("k", "nprobe", "block_d", "interpret",
                                    "absolute"))
 def ivf_probe_topk_batch(cents: jax.Array, cell_rows: jax.Array,
                          cells: jax.Array, Vb: jax.Array, k: int, nprobe: int,
-                         *, block_d: int = 512, interpret: bool | None = None,
+                         *, block_d: int = 2048,
+                         interpret: bool | None = None,
                          absolute: bool = False):
     """Wave-batched fused IVF probe over B probe vectors ``Vb`` (B, dim).
 
-    Each cell of the lanes' deduplicated union streams HBM→VMEM once
-    (duplicate tail slots revisit the resident block, lane-membership
-    masked); each streamed tile is scored against the whole wave by one
-    MXU matmul. Returns ``(idx (B, k), scores (B, k), n_valid (B,))`` —
-    bitwise `ref.ivf_probe_topk_batch_ref` (ties break in ascending-cell
-    slot order, see ref.py).
+    Each cell of the lanes' deduplicated union streams HBM→VMEM once;
+    each streamed tile is scored against the whole wave by one MXU
+    contraction. Returns ``(idx (B, k), scores (B, k), n_valid (B,))`` —
+    `ref.ivf_probe_topk_batch_ref`'s candidates in its order (ties break
+    in ascending-cell slot order, see ref.py).
     """
     nlist, cap, dim = cell_rows.shape
     B = Vb.shape[0]
-    block_d = min(block_d, max(8, dim))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     slots, member, probe = batch_probe_slots(cents, cells, Vb, nprobe,
                                              absolute)
-    rows_p, ids_p = _pad_cell_blocks(cell_rows, cells, block_d)
-    qbp = _pad_to(Vb.T, 0, block_d)                       # (dp, B)
+    interpret = pallas_route("ivf_probe_batch", interpret)
     with obs_scope("kernel/ivf_probe_batch"):
-        out_i, out_s = ivf_probe_stream_batch_pallas(
-            slots, rows_p, ids_p, qbp, member, k, block_d=block_d,
-            interpret=interpret, absolute=absolute)
+        s = ivf_scores_pallas(slots, cell_rows, Vb,
+                              block_d=pick_block(dim, block_d),
+                              interpret=interpret)       # (S, B, cap)
+    ids = jnp.where(member.T[:, :, None] > 0,
+                    _cell_ids(cells, slots, cap)[None], -1)  # (B, S, cap)
+    out_i, out_s = _top_candidates(
+        jnp.swapaxes(s, 0, 1).reshape(B, -1), ids.reshape(B, -1), k,
+        absolute)
     n_valid = jnp.sum(cells[probe] >= 0, axis=(1, 2)).astype(jnp.int32)
     return out_i, out_s, n_valid

@@ -19,6 +19,23 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.precision import dot as _dot
+
+
+def masked_top_k(scores: jax.Array, ids: jax.Array, k: int):
+    """Stable top-k over (..., n) candidate ``scores`` (masked slots −inf)
+    with their ``ids``; ranks past the finite candidates — including
+    ranks past ``n`` when fewer than k candidates were probed — get id −1
+    and score −inf. Returns ``(idx int32 (..., k), scores (..., k))``."""
+    short = k - scores.shape[-1]
+    if short > 0:
+        pad = [(0, 0)] * (scores.ndim - 1) + [(0, short)]
+        scores = jnp.pad(scores, pad, constant_values=-jnp.inf)
+        ids = jnp.pad(ids, pad, constant_values=-1)
+    top_s, pos = jax.lax.top_k(scores, k)
+    top_i = jnp.take_along_axis(ids, pos, axis=-1)
+    return jnp.where(jnp.isfinite(top_s), top_i, -1).astype(jnp.int32), top_s
+
 
 def ivf_probe_topk_ref(cents: jax.Array, cells: jax.Array, V: jax.Array,
                        q: jax.Array, k: int, nprobe: int,
@@ -29,18 +46,17 @@ def ivf_probe_topk_ref(cents: jax.Array, cells: jax.Array, V: jax.Array,
     valid candidates were probed); ``n_valid`` counts the valid (non-pad)
     row slots in the probed cells — the scored-rows term of ``n_scored``.
     """
-    cscores = cents.astype(jnp.float32) @ q.astype(jnp.float32)
+    cscores = _dot(cents, q)
     order = jnp.abs(cscores) if absolute else cscores
     _, probe = jax.lax.top_k(order, nprobe)
     cand = cells[probe].reshape(-1)                       # (nprobe·cap,)
     valid = cand >= 0
-    scores = V[jnp.clip(cand, 0)].astype(jnp.float32) @ q.astype(jnp.float32)
+    scores = _dot(V[jnp.clip(cand, 0)], q)
     if absolute:
         scores = jnp.abs(scores)
     scores = jnp.where(valid, scores, -jnp.inf)
-    top_s, pos = jax.lax.top_k(scores, k)
-    idx = jnp.where(jnp.isfinite(top_s), cand[pos], -1)
-    return idx.astype(jnp.int32), top_s, jnp.sum(valid).astype(jnp.int32)
+    idx, top_s = masked_top_k(scores, cand, k)
+    return idx, top_s, jnp.sum(valid).astype(jnp.int32)
 
 
 def batch_probe_slots(cents: jax.Array, cells: jax.Array, Vb: jax.Array,
@@ -54,7 +70,7 @@ def batch_probe_slots(cents: jax.Array, cells: jax.Array, Vb: jax.Array,
     re-streaming distinct cells), the (B·nprobe, B) float 0/1 membership
     mask, and the per-lane (B, nprobe) probed cells.
     """
-    cscores = Vb.astype(jnp.float32) @ cents.astype(jnp.float32).T  # (B, nlist)
+    cscores = _dot(Vb, cents.T)                           # (B, nlist)
     order = jnp.abs(cscores) if absolute else cscores
     _, probe = jax.lax.top_k(order, nprobe)               # (B, nprobe)
     flat = jnp.sort(probe.reshape(-1))
@@ -121,7 +137,8 @@ def ivf_probe_topk_batch_ref(cents: jax.Array, cells: jax.Array,
                                              absolute)
     cand = cells[slots]                                   # (S, cap)
     scores = jnp.einsum("scd,bd->bsc", V[jnp.clip(cand, 0)].astype(jnp.float32),
-                        Vb.astype(jnp.float32))           # (B, S, cap)
+                        Vb.astype(jnp.float32),
+                        precision="highest")              # (B, S, cap)
     if absolute:
         scores = jnp.abs(scores)
     mask = (cand[None, :, :] >= 0) & (member.T[:, :, None] > 0)
@@ -129,8 +146,6 @@ def ivf_probe_topk_batch_ref(cents: jax.Array, cells: jax.Array,
     B = Vb.shape[0]
     flat_s = scores.reshape(B, -1)
     flat_i = jnp.broadcast_to(cand.reshape(-1)[None, :], flat_s.shape)
-    top_s, pos = jax.lax.top_k(flat_s, k)
-    idx = jnp.where(jnp.isfinite(top_s),
-                    jnp.take_along_axis(flat_i, pos, axis=1), -1)
+    idx, top_s = masked_top_k(flat_s, flat_i, k)
     n_valid = jnp.sum(cells[probe] >= 0, axis=(1, 2)).astype(jnp.int32)
-    return idx.astype(jnp.int32), top_s, n_valid
+    return idx, top_s, n_valid

@@ -1,119 +1,73 @@
-"""Streaming tiled top-k MIPS Pallas kernel.
+"""Streaming tiled inner-product scoring kernel for the flat MIPS scan.
 
 The flat (exact) retrieval path of Fast-MWEM: score all n key vectors
-against one probe and keep the top-k — without ever materializing the
-(n,) score vector in HBM.
+against one probe, then keep the top-k. The kernel streams V HBM→VMEM in
+(block_n × block_d) tiles and accumulates each row tile's partial dots in
+its resident (1, block_n) output block across the d-tiles; only the
+(n,) score vector — 1/d of the table's bytes — leaves the chip, and the
+top-k over it runs in XLA (`ops.py`). Mosaic has no in-kernel `top_k`,
+and the score vector is small next to the table, so V is still read
+exactly once. Arithmetic intensity is ~0.5 flop/byte: the kernel is
+HBM-bandwidth-bound by construction, which is the roofline the IVF/LSH/NSW
+indices beat by touching fewer rows.
 
-TPU mapping: V streams HBM→VMEM in (block_n × block_d) tiles; partial dot
-products accumulate across the d-tiles in a VMEM scratch; when a row tile's
-score is complete it is merged into a running top-k scratch via
-`jax.lax.top_k` over the (k + block_n) concatenation. Arithmetic intensity
-is ~0.5 flop/byte — the kernel is HBM-bandwidth-bound by construction, which
-is the roofline the IVF/LSH/NSW indices beat by touching fewer rows.
+Scores are lane-dense: each tile is one ``q (1, block_d) · V_tileᵀ``
+contraction at f32 precision (``Precision.HIGHEST`` — the MXU's default
+bf16 operand rounding would change the exponential mechanism's scores).
 
-Three ranking modes share the one streaming pass (``mode``):
-
-* ``"plain"`` — rank by ⟨v_j, q⟩, return row ids (the exact flat scan).
-* ``"abs"``   — rank by |⟨v_j, q⟩|, return row ids and the absolute
-  scores (the IVF centroid-probe ordering of the sharded driver).
-* ``"aug"``   — rank the complement-augmented set: each row contributes
-  both signed scores (+⟨v_j, q⟩ as id j, −⟨v_j, q⟩ as id j+n) to a single
-  top-k merge. One read of V covers both signs — half the HBM traffic of
-  the old two-pass (q, −q) formulation.
-
-Grid: (n_tiles, d_tiles), d innermost. All shapes padded by ops.py.
+Grid: (n_tiles, d_tiles), d innermost. ``block_d`` divides d (ops.py picks
+it), so no padded column ever enters a sum; a ragged last row tile reads
+rows past n whose scores land in the output's masked tail and are never
+written.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.precision import HIGHEST
+
+# contract the last dim of both operands: (1, bd) · (bn, bd)ᵀ → (1, bn)
+_NT = (((1,), (1,)), ((), ()))
 
 
-def _kernel(v_ref, q_ref, out_i_ref, out_s_ref, acc_ref, top_s_ref, top_i_ref,
-            *, k: int, block_n: int, n_real: int, mode: str):
-    ni = pl.program_id(0)
-    di = pl.program_id(1)
-    nd = pl.num_programs(1)
-
-    @pl.when(di == 0)
-    def _init_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # (block_n, block_d) @ (block_d,) partial scores, f32 accumulation.
-    acc_ref[...] += v_ref[...].astype(jnp.float32) @ q_ref[...].astype(jnp.float32)
-
-    @pl.when(di == nd - 1)
-    def _merge():
-        @pl.when(ni == 0)
-        def _init_top():
-            top_s_ref[...] = jnp.full_like(top_s_ref, -jnp.inf)
-            top_i_ref[...] = jnp.zeros_like(top_i_ref)
-
-        row_idx = ni * block_n + jax.lax.iota(jnp.int32, block_n)
-        valid = row_idx < n_real
-        acc = acc_ref[...]
-        if mode == "plain":
-            scores = jnp.where(valid, acc, -jnp.inf)
-            cand_i = row_idx
-        elif mode == "abs":
-            scores = jnp.where(valid, jnp.abs(acc), -jnp.inf)
-            cand_i = row_idx
-        elif mode == "aug":
-            # Both signs of every row in one merge: id j ↦ +score,
-            # id j+n ↦ −score (the complement row, paper §3.4).
-            scores = jnp.concatenate([
-                jnp.where(valid, acc, -jnp.inf),
-                jnp.where(valid, -acc, -jnp.inf),
-            ])
-            cand_i = jnp.concatenate([row_idx, row_idx + n_real])
-        else:
-            raise ValueError(f"unknown mips_topk mode {mode!r}")
-        merged_s = jnp.concatenate([top_s_ref[...], scores])
-        merged_i = jnp.concatenate([top_i_ref[...], cand_i])
-        new_s, pos = jax.lax.top_k(merged_s, k)
-        top_s_ref[...] = new_s
-        top_i_ref[...] = merged_i[pos]
-
-        @pl.when(ni == pl.num_programs(0) - 1)
-        def _emit():
-            out_s_ref[...] = top_s_ref[...]
-            out_i_ref[...] = top_i_ref[...]
+def row_scores(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a · bᵀ`` for (A, d) and (R, d) operands → (A, R), one f32-precision
+    contraction per pair of rows (probes against table rows, or rows
+    against a (1, d) probe)."""
+    return jax.lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32), _NT,
+        precision=HIGHEST, preferred_element_type=jnp.float32)
 
 
-def mips_topk_pallas(Vp: jax.Array, qp: jax.Array, k: int, *, block_n: int,
-                     block_d: int, interpret: bool, n_real: int,
-                     mode: str = "plain"):
-    """Padded-shape pallas_call; use ops.mips_topk for the public API."""
-    n, d = Vp.shape
-    assert n % block_n == 0 and d % block_d == 0, "ops.py must pad"
-    grid = (n // block_n, d // block_d)
-    kern = functools.partial(_kernel, k=k, block_n=block_n, n_real=n_real,
-                             mode=mode)
-    out_i, out_s = pl.pallas_call(
-        kern,
+def _kernel(v_ref, q_ref, out_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    out_ref[...] += row_scores(q_ref[...], v_ref[...])
+
+
+def mips_scores_pallas(V: jax.Array, q2: jax.Array, *, block_n: int,
+                       block_d: int, interpret: bool) -> jax.Array:
+    """``(1, n)`` f32 scores ``V @ q``; use ops.mips_topk for the public API.
+
+    ``q2`` is the probe as a (1, d) row; ``block_d`` must divide d.
+    """
+    n, d = V.shape
+    assert d % block_d == 0, "ops.py picks a block_d that divides d"
+    grid = (pl.cdiv(n, block_n), d // block_d)
+    return pl.pallas_call(
+        _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((block_d,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_d), lambda i, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((k,), lambda i, j: (0,)),
-            pl.BlockSpec((k,), lambda i, j: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k,), jnp.int32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_n,), jnp.float32),
-            pltpu.VMEM((k,), jnp.float32),
-            pltpu.VMEM((k,), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
-    )(Vp, qp)
-    return out_i, out_s
+        name="mips_scores",
+    )(V, q2)

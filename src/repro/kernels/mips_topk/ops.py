@@ -1,4 +1,4 @@
-"""Public jit'd wrapper for the mips_topk kernel (padding + dispatch)."""
+"""Public jit'd wrappers for the mips_topk kernel (tiling + top-k)."""
 
 from __future__ import annotations
 
@@ -7,64 +7,68 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.mips_topk.mips_topk import mips_topk_pallas
+from repro.kernels.mips_topk.mips_topk import mips_scores_pallas
+from repro.kernels.route import pallas_route
 
 
-def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
-    pad = (-x.shape[axis]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
+def pick_block(size: int, pref: int, align: int = 128) -> int:
+    """Largest multiple of ``align`` that divides ``size`` and is ≤ ``pref``;
+    the whole axis when none does. A block that divides the axis needs no
+    padded copy of the table on any call."""
+    b = (min(pref, size) // align) * align
+    while b >= align:
+        if size % b == 0:
+            return b
+        b -= align
+    return size
+
+
+def _row_block(n: int, pref: int) -> int:
+    """Row tile of the (1, n) score output: a lane multiple, or all of n."""
+    return n if n <= pref else max(128, (pref // 128) * 128)
+
+
+def _scores(V, q, block_n, block_d, interpret, name):
+    n, d = V.shape
+    interpret = pallas_route(name, interpret)
+    return mips_scores_pallas(V, q.reshape(1, d),
+                              block_n=_row_block(n, block_n),
+                              block_d=pick_block(d, block_d),
+                              interpret=interpret)[0]
 
 
 @partial(jax.jit,
          static_argnames=("k", "block_n", "block_d", "interpret", "absolute"))
 def mips_topk(V: jax.Array, q: jax.Array, k: int, *, block_n: int = 512,
-              block_d: int = 512, interpret: bool | None = None,
+              block_d: int = 1024, interpret: bool | None = None,
               absolute: bool = False):
     """Top-k inner products of ``q`` against rows of ``V``.
 
-    Pads (n, d) to tile multiples; padded rows are masked inside the kernel
-    (scores forced to −inf). ``interpret=None`` → interpret everywhere
-    except real TPU backends. ``absolute=True`` ranks by |⟨v_j, q⟩| and
-    returns the absolute scores (the IVF centroid-probe ordering) — ties
-    break exactly like ``jax.lax.top_k`` on the full score vector.
+    The kernel streams V once and emits the (n,) scores; `jax.lax.top_k`
+    ranks them, so ties break exactly like `ref.mips_topk_ref`.
+    ``absolute=True`` ranks by |⟨v_j, q⟩| and returns the absolute scores
+    (the IVF centroid-probe ordering).
     """
-    n, d = V.shape
-    block_n = min(block_n, max(8, n))
-    block_d = min(block_d, max(8, d))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    Vp = _pad_to(_pad_to(V, 0, block_n), 1, block_d)
-    qp = _pad_to(q, 0, block_d)
-    return mips_topk_pallas(Vp, qp, k, block_n=block_n, block_d=block_d,
-                            interpret=interpret, n_real=n,
-                            mode="abs" if absolute else "plain")
+    s = _scores(V, q, block_n, block_d, interpret,
+                "mips_topk_abs" if absolute else "mips_topk")
+    top_s, top_i = jax.lax.top_k(jnp.abs(s) if absolute else s, k)
+    return top_i.astype(jnp.int32), top_s
 
 
 @partial(jax.jit, static_argnames=("k", "block_n", "block_d", "interpret"))
 def mips_abs_topk(V: jax.Array, q: jax.Array, k: int, *, block_n: int = 512,
-                  block_d: int = 512, interpret: bool | None = None):
+                  block_d: int = 1024, interpret: bool | None = None):
     """Top-k of ``|V @ q|`` as complement-augmented ids (paper §3.4).
 
     Returned id ``j < n`` means ``+⟨v_j, q⟩``; ``j ≥ n`` means
     ``−⟨v_{j−n}, q⟩`` (the complement row's score for zero-sum probes).
-    One streaming pass over V: each row tile contributes *both* signed
-    scores to the running top-k merge (``mode="aug"``), so the 2n-row
-    augmented matrix is never materialized and V is read exactly once —
-    half the HBM traffic of the old two-pass (q, −q) formulation. For
-    k ≤ n each base row contributes at most one of its two signed scores
-    to the top (the other is ≤ 0 ≤ the winner), so this equals top-k over
-    the full augmented set.
+    One streaming pass over V; the 2n-row augmented matrix is never
+    materialized. The ranking is `mips.flat`'s XLA abs probe on the
+    kernel's scores (each base row contributes its larger signed score),
+    so the two routes pick the same ids from the same scores. ``k ≤ n``.
     """
-    n, d = V.shape
-    block_n = min(block_n, max(8, n))
-    block_d = min(block_d, max(8, d))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    Vp = _pad_to(_pad_to(V, 0, block_n), 1, block_d)
-    qp = _pad_to(q, 0, block_d)
-    return mips_topk_pallas(Vp, qp, k, block_n=block_n, block_d=block_d,
-                            interpret=interpret, n_real=n, mode="aug")
+    n = V.shape[0]
+    s = _scores(V, q, block_n, block_d, interpret, "mips_topk_aug")
+    top_a, top_i = jax.lax.top_k(jnp.abs(s), k)
+    aug = jnp.where(s[top_i] >= 0, top_i, top_i + n)
+    return aug.astype(jnp.int32), top_a

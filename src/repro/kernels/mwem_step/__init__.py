@@ -1,11 +1,9 @@
-from repro.kernels.mwem_step.ops import (aug_gather_score, mwem_step,
-                                         mwem_step_batch,
+from repro.kernels.mwem_step.ops import (mwem_step, mwem_step_batch,
                                          mwem_step_supported, mwu_apply)
 from repro.kernels.mwem_step.ref import (UPDATE_RULES, mwem_step_ref,
                                          mwu_apply_ref)
 
 __all__ = [
-    "aug_gather_score",
     "mwem_step",
     "mwem_step_batch",
     "mwem_step_supported",

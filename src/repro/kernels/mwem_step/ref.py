@@ -47,8 +47,9 @@ def mwu_apply_ref(log_w: jax.Array, p: jax.Array, q_row: jax.Array,
     if rule == "paper":
         lw = log_w - eta * q_row
     else:
-        measured = q_row @ h + noise
-        est = q_row @ p
+        # f32 products summed along U — the megakernel's expression
+        measured = jnp.sum(q_row * h) + noise
+        est = jnp.sum(q_row * p)
         if rule == "signed":
             lw = log_w + eta * jnp.sign(measured - est) * q_row
         elif rule == "hardt":

@@ -1,13 +1,9 @@
 """Mesh construction (assignment §MULTI-POD DRY-RUN + the sharded driver).
 
 Functions — not module-level constants — so importing this module never
-touches JAX device state.
-
-``make_mesh_compat`` papers over the ``jax.sharding.AxisType`` API churn:
-newer JAX wants explicit axis types on ``jax.make_mesh`` while older
-releases raise ``AttributeError`` on the mere mention of the enum. Every
-mesh in the repo (production dry-run, tests, the sharded MWEM driver) goes
-through it so a JAX upgrade is a one-line change.
+touches JAX device state. Every mesh in the repo (production dry-run,
+tests, the sharded MWEM driver) goes through `make_mesh`, which asks for
+Auto axis types: sharding follows the in/out specs the drivers give.
 """
 
 from __future__ import annotations
@@ -17,20 +13,16 @@ import math
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types when the installed JAX has
-    them, plain positional form otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` over the visible devices, with Auto axis types."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_driver_mesh(n_devices: int | None = None, *, model_degree: int = 1):
@@ -42,8 +34,8 @@ def make_driver_mesh(n_devices: int | None = None, *, model_degree: int = 1):
     if n_devices % model_degree:
         raise ValueError(f"model_degree {model_degree} does not divide "
                          f"device count {n_devices}")
-    return make_mesh_compat((n_devices // model_degree, model_degree),
-                            ("data", "model"))
+    return make_mesh((n_devices // model_degree, model_degree),
+                     ("data", "model"))
 
 
 def batch_axes(multi_pod: bool):

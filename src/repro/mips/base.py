@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import copy
 from typing import Protocol, Tuple, runtime_checkable
 
 import jax
@@ -20,6 +21,30 @@ def resolve_pallas(use_pallas: str) -> bool:
         raise ValueError(f"use_pallas must be auto|always|never, "
                          f"got {use_pallas!r}")
     return jax.default_backend() == "tpu"
+
+
+def index_state(index) -> dict:
+    """The device state an index holds: its array and workload attributes.
+
+    Jitted drivers take this dict as an argument and call the index through
+    `bind_state`, so an index's tables reach the executable as parameters —
+    a closed-over array would be captured into the program as a constant,
+    several GB of it at a real table size."""
+    if index is None:
+        return {}
+    from repro.core.workload import Workload
+
+    return {k: v for k, v in vars(index).items()
+            if isinstance(v, (jax.Array, Workload))}
+
+
+def bind_state(index, state: dict):
+    """A shallow copy of ``index`` holding ``state`` (tracers, in a trace)."""
+    if index is None:
+        return None
+    bound = copy.copy(index)
+    bound.__dict__.update(state)
+    return bound
 
 
 @runtime_checkable

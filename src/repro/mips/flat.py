@@ -21,6 +21,7 @@ import numpy as np
 from repro.core.workload import Workload, as_workload
 from repro.faults import fault_site
 from repro.mips.base import resolve_pallas
+from repro.precision import dot as _dot
 
 
 @partial(jax.jit, static_argnames=("k", "pallas"))
@@ -29,7 +30,7 @@ def _flat_query(vectors, q, k: int, pallas: bool):
         from repro.kernels.mips_topk import ops as topk_ops
 
         return topk_ops.mips_topk(vectors, q, k)
-    scores = vectors @ q
+    scores = _dot(vectors, q)
     top_s, top_i = jax.lax.top_k(scores, k)
     return top_i.astype(jnp.int32), top_s
 
@@ -47,7 +48,7 @@ def _flat_abs_query(Qm, v, k: int, pallas: bool):
 @partial(jax.jit, static_argnames=("k",))
 def _flat_abs_query_scores(Qm, v, k: int):
     m = Qm.shape[0]
-    s = Qm @ v
+    s = _dot(Qm, v)
     a = jnp.abs(s)
     top_a, top_i = jax.lax.top_k(a, k)
     aug = jnp.where(s[top_i] >= 0, top_i, top_i + m)
@@ -75,7 +76,7 @@ def _flat_abs_query_batch(Qm, Vb, k: int):
     matmul reads Q once for every lane — already the amortization the
     batched IVF kernel buys, so no Pallas variant is needed here."""
     m = Qm.shape[0]
-    s = Vb @ Qm.T                                       # (B, m)
+    s = _dot(Vb, Qm.T)                                  # (B, m)
     top_a, top_i = jax.lax.top_k(jnp.abs(s), k)
     aug = jnp.where(jnp.take_along_axis(s, top_i, axis=1) >= 0,
                     top_i, top_i + m)
@@ -113,8 +114,8 @@ class FlatAbsIndex:
 
     Returns *augmented* ids (j < m ⇒ +⟨q_j, v⟩; j ≥ m ⇒ −⟨q_{j−m}, v⟩),
     matching the convention of `augment_complement`. On TPU the scan runs
-    through the streaming `mips_abs_topk` kernel — one pass over Q merges
-    both signs' candidates (half the HBM traffic of the old two-pass).
+    through the streaming `mips_abs_topk` kernel — one pass over Q scores
+    both signs (half the HBM traffic of scoring q and −q).
     """
 
     approx_margin = 0.0

@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.faults import fault_site
 from repro.mips.base import resolve_pallas
+from repro.precision import dot as _dot
 
 
 def _vectors_from(vectors, context: str) -> np.ndarray:
@@ -49,7 +50,7 @@ def _vectors_from(vectors, context: str) -> np.ndarray:
     from repro.core.workload import Workload
 
     if isinstance(vectors, Workload):
-        return vectors.require_dense(context)
+        return np.asarray(vectors.require_dense(context), np.float32)
     return np.asarray(vectors, np.float32)
 
 
@@ -113,17 +114,24 @@ def _balanced_assign(V: np.ndarray, cents: np.ndarray, cap: int) -> np.ndarray:
 # seed used retraced per tenant/index build).
 
 def _query_impl(V, cents, cells, q, k: int, nprobe: int):
-    cscores = cents @ q
+    cscores = _dot(cents, q)
     _, probe = jax.lax.top_k(cscores, nprobe)
     cand = cells[probe].reshape(-1)                    # (nprobe·cap,)
     valid = cand >= 0
-    scores = V[jnp.clip(cand, 0)] @ q
+    scores = _dot(V[jnp.clip(cand, 0)], q)
     scores = jnp.where(valid, scores, -jnp.inf)
     top_s, pos = jax.lax.top_k(scores, k)
     return cand[pos].astype(jnp.int32), top_s
 
 
 _query_xla = jax.jit(_query_impl, static_argnames=("k", "nprobe"))
+
+
+@jax.jit
+def _group_rows(V, cells):
+    rows = jnp.take(V, jnp.clip(cells, 0), axis=0) * (cells >= 0)[..., None]
+    pad = (-cells.shape[1]) % 8
+    return jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
 
 
 @partial(jax.jit, static_argnames=("k", "nprobe"))
@@ -164,20 +172,14 @@ class IVFIndex:
     def _rows_by_cell(self) -> jax.Array:
         """(nlist, cap⌈8⌉, dim) rows in cell-grouped layout — the
         contiguous HBM blocks the kernel's scalar-prefetched index_map
-        streams. The cap axis is pre-padded to the sublane multiple so the
-        per-call `_pad_cell_blocks` in ops.py is a no-op on the hot path
-        (no per-probe copy of the whole table). Usually built at __init__;
-        the lazy rebuild (a flipped `use_pallas` knob) pins compile-time
-        eval so a driver tracing through the index can never cache a
-        tracer here."""
+        streams, the cap axis padded to the sublane multiple. Built once,
+        as one fused program, so the device never holds more than the
+        finished table beside ``V``. Usually built at __init__; the lazy
+        rebuild (a flipped `use_pallas` knob) pins compile-time eval so a
+        driver tracing through the index can never cache a tracer here."""
         if self._cell_rows is None:
             with jax.ensure_compile_time_eval():
-                rows = (jnp.take(self._v, jnp.clip(self._cells, 0), axis=0)
-                        * (self._cells >= 0)[..., None])
-                pad = (-self.cap) % 8
-                if pad:
-                    rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
-                self._cell_rows = rows
+                self._cell_rows = _group_rows(self._v, self._cells)
         return self._cell_rows
 
     def query(self, v, k: int):
@@ -232,8 +234,7 @@ class ShardedIVFIndex:
     body (``supports_sharded``), where each shard probes its own cells and
     candidates meet at the all-gather. ``use_pallas`` routes that per-shard
     probe through the fused `kernels.ivf_probe` kernel when the mesh has no
-    model sharding (the kernel fuses dot+top-k, so partial-dot psums can't
-    interpose); the driver falls back to XLA automatically otherwise.
+    model sharding (`kernel_probe`, which the driver asks).
     """
 
     supports_in_graph = False
@@ -268,8 +269,12 @@ class ShardedIVFIndex:
         self.approx_margin = approx_margin
         self.failure_mass = (1.0 / self.n) if failure_mass is None else failure_mass
 
-    def _resolve_pallas(self) -> bool:
-        return resolve_pallas(self._use_pallas)
+    def kernel_probe(self, n_model: int) -> bool:
+        """Whether the per-shard probe runs the fused kernel on a mesh whose
+        "model" axis has extent ``n_model``: the kernel fuses dot and top-k,
+        so the partial-dot psum of a model-sharded probe cannot interpose —
+        such meshes probe through XLA."""
+        return resolve_pallas(self._use_pallas) and n_model == 1
 
     def query_cost(self, k: int) -> int:
         """Scored rows per iteration across all shards (excluding the tail)."""

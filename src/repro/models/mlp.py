@@ -28,7 +28,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -175,13 +174,13 @@ def moe_mlp_ep(p, x, cfg: ModelConfig, mesh, rules):
         y = jax.lax.psum(y, model_ax)
         return y.reshape(Bl, Sl, Dl)
 
-    y = shard_map(
+    y = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_ax, None, None), P(None, model_ax),
                   P(model_ax, None, None), P(model_ax, None, None),
                   P(model_ax, None, None)),
         out_specs=P(batch_ax, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if cfg.moe_shared_expert:

@@ -77,6 +77,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.accountant import PrivacyLedger
 from repro.core.distributed import _data_shards, run_mwem_sharded_batch
@@ -87,7 +88,7 @@ from repro.core.lp_scalar import (LPPendingBatch, ScalarLPConfig,
 from repro.core.mwem import (MWEMConfig, MWEMPendingBatch, aot_compile_batch,
                              finish_mwem_batch, launch_mwem_batch,
                              release_cost, run_mwem_batch)
-from repro.core.workload import as_workload
+from repro.core.workload import Workload, as_workload
 from repro.faults import fault_site
 from repro.mips import (FlatAbsIndex, FlatIndex, IVFIndex, LSHIndex,
                         MarginalIVFIndex, ShardedIVFIndex,
@@ -226,9 +227,18 @@ class ReleaseService:
                  max_queue_depth: Optional[int] = None,
                  breaker_threshold: int = 3, streaming: bool = False,
                  policy=None):
+        if streaming and mesh is not None:
+            raise ValueError(
+                "streaming waves are single-device: the sharded driver "
+                "dispatches lanes sequentially with no launch/finish split")
         # the workload seam: a raw (m, U) matrix or any `core.workload`
         # family — `MarginalWorkload` releases run factored end to end
         # through the same admission/cost/wave path (DESIGN.md §9)
+        if mesh is not None and not isinstance(Q, Workload):
+            # rows over the mesh's data axes from the start, as the sharded
+            # driver holds them — no full replica on the default device
+            Q = jax.device_put(np.asarray(Q, np.float32), NamedSharding(
+                mesh, PartitionSpec(_data_shards(mesh)[0], "model")))
         self.workload = as_workload(Q)
         self.Q = self.workload.Q if self.workload.is_dense else None
         self.m, self.U = self.workload.m, self.workload.U
@@ -268,10 +278,6 @@ class ReleaseService:
         # deadline/occupancy coalescer cuts adaptive-size waves, dispatch
         # is pipelined launch/finish with one wave in flight
         self.streaming = bool(streaming)
-        if self.streaming and mesh is not None:
-            raise ValueError(
-                "streaming waves are single-device: the sharded driver "
-                "dispatches lanes sequentially with no launch/finish split")
         self.policy = (policy if policy is not None else
                        (DeadlineOccupancyPolicy(wave_size=self.wave_size)
                         if self.streaming else None))
@@ -606,8 +612,9 @@ class ReleaseService:
 
     def _degrade_to_ref(self) -> None:
         """Breaker trip: pin the service to the XLA reference route. The
-        megakernel and classic paths are bitwise-identical (DESIGN.md §7),
-        so degradation changes throughput, never answers."""
+        megakernel and classic paths compute the same expressions (on the
+        chip up to f32 reduction order, DESIGN.md §7), so degradation
+        changes throughput, not the mechanism."""
         self.cfg = replace(self.cfg, use_pallas="never")
         indexes = [self.index]
         if self.lp is not None:

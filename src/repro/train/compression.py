@@ -40,21 +40,12 @@ def _dequantize(q, scale, n):
     return (q.astype(jnp.float32) * scale).reshape(-1)[:n]
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static axis size inside shard_map/pmap. ``jax.lax.axis_size`` is
-    recent API; older JAX gets it from the constant-folded ``psum(1, ·)``."""
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return int(size(axis_name))
-    return int(jax.lax.psum(1, axis_name))
-
-
 def ring_allreduce_int8(x: jax.Array, axis_name: str) -> jax.Array:
     """Mean all-reduce of a flat f32 vector with int8 wire format.
 
     Must run inside shard_map/pmap over ``axis_name``.
     """
-    P = _axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     if P == 1:
         return x
     idx = jax.lax.axis_index(axis_name)

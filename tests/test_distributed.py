@@ -3,10 +3,10 @@ int8 error-feedback all-reduce, the sharded Fast-MWEM driver (host-parity
 selections, overflow fallback, ledger totals, service waves on a mesh),
 dry-run machinery on a small mesh.
 
-All inline scripts build meshes through `repro.launch.mesh.make_mesh_compat`
-— constructing them with ``axis_types=`` directly crashes on JAX versions
-without `jax.sharding.AxisType` (the seed-suite failure this file used to
-reproduce)."""
+All inline scripts build meshes through `repro.launch.mesh.make_mesh`
+(Auto axis types) and run on forced CPU host devices: each child is given
+``JAX_PLATFORMS=cpu`` explicitly, so none of them reaches for an
+accelerator the parent may hold."""
 
 import json
 import os
@@ -22,6 +22,7 @@ REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def _run(script: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO_SRC
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
                          capture_output=True, text=True, env=env, timeout=600)
@@ -31,11 +32,11 @@ def _run(script: str, devices: int = 8) -> str:
 
 class TestMeshCompat:
     def test_make_mesh_compat_no_axis_type_attribute_error(self):
-        """`make_mesh_compat` must work whether or not the installed JAX
-        exposes jax.sharding.AxisType (the seed crash)."""
+        """`make_mesh` builds Auto-typed meshes with the installed JAX's
+        `jax.sharding.AxisType` (the seed crashed on its absence)."""
         out = _run("""
-            from repro.launch.mesh import make_mesh_compat, make_driver_mesh
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh, make_driver_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             assert mesh.shape == {"data": 4, "model": 2}
             mesh2 = make_driver_mesh(8, model_degree=2)
             assert mesh2.shape == {"data": 4, "model": 2}
@@ -48,16 +49,16 @@ class TestCompression:
     def test_ring_allreduce_int8_matches_mean(self):
         out = _run("""
             import jax, jax.numpy as jnp, numpy as np
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.train.compression import ring_allreduce_int8
-            from repro.launch.mesh import make_mesh_compat
-            mesh = make_mesh_compat((8,), ("pod",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8,), ("pod",))
             n = 4096
             xs = jax.random.normal(jax.random.PRNGKey(0), (8, n))
             f = shard_map(lambda x: ring_allreduce_int8(x[0], "pod")[None],
                           mesh=mesh, in_specs=P("pod", None),
-                          out_specs=P("pod", None), check_rep=False)
+                          out_specs=P("pod", None), check_vma=False)
             got = np.asarray(f(xs))
             want = np.asarray(xs.mean(0))
             for i in range(8):
@@ -71,11 +72,11 @@ class TestCompression:
     def test_error_feedback_reduces_bias(self):
         out = _run("""
             import jax, jax.numpy as jnp, numpy as np
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.train.compression import ef_allreduce_grads
-            from repro.launch.mesh import make_mesh_compat
-            mesh = make_mesh_compat((4,), ("pod",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ("pod",))
             grads = {"w": jax.random.normal(jax.random.PRNGKey(1), (4, 1000))}
             def step(g, err):
                 out, st = ef_allreduce_grads({"w": g["w"][0]},
@@ -84,7 +85,7 @@ class TestCompression:
             f = shard_map(step, mesh=mesh,
                           in_specs=(P("pod", None), P("pod", None)),
                           out_specs=(P("pod", None), P("pod", None)),
-                          check_rep=False)
+                          check_vma=False)
             err = jnp.zeros((4, 1000))
             acc_true = np.zeros(1000)
             acc_comp = np.zeros(1000)
@@ -106,8 +107,8 @@ class TestDistributedMWEM:
         out = _run("""
             import jax, jax.numpy as jnp, numpy as np, math
             from repro.core.distributed import make_mwem_iteration
-            from repro.launch.mesh import make_mesh_compat
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             m, U = 1024, 64
             n_data, m_loc = 4, 256
             fn = make_mwem_iteration(mesh, m=m, U=U, nlist=32, cap=16,
@@ -142,8 +143,8 @@ class TestDistributedMWEM:
         out = _run("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.core.distributed import make_mwem_iteration
-            from repro.launch.mesh import make_mesh_compat
-            mesh = make_mesh_compat((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("data", "model"))
             m, U, nlist, cap, nprobe = 256, 32, 16, 16, 4
             n_data, m_loc = 2, 128
             # scale ≫ Gumbel spread → the tail margin B is huge, C = 0, and
@@ -182,8 +183,8 @@ class TestDistributedMWEM:
             import jax, jax.numpy as jnp, numpy as np
             from repro.core.distributed import make_mwem_iteration
             from repro.analysis.hlo import analyze_hlo
-            from repro.launch.mesh import make_mesh_compat
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             # sublinearity needs m_loc ≫ √m_loc·probe width — use a scale
             # where the exhaustive psum of m_loc scores dominates
             m, U = 262144, 64
@@ -220,12 +221,12 @@ class TestShardedDriver:
             from repro.core import MWEMConfig, run_mwem, run_mwem_sharded
             from repro.core.queries import (gaussian_histogram,
                                             random_binary_queries)
-            from repro.launch.mesh import make_mesh_compat
+            from repro.launch.mesh import make_mesh
             kh, kq = jax.random.split(jax.random.PRNGKey(0))
             U, m, n = 64, 512, 300
             h = gaussian_histogram(kh, n, U)
             Q = random_binary_queries(kq, m, U)
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             cfg = MWEMConfig(T=8, mode="exact", n_records=n)
             cfg_host = MWEMConfig(T=8, mode="exact", n_records=n,
                                   driver="host")
@@ -253,12 +254,12 @@ class TestShardedDriver:
                                             random_binary_queries)
             from repro.mips import (IVFIndex, ShardedIVFIndex,
                                     augment_complement)
-            from repro.launch.mesh import make_mesh_compat
+            from repro.launch.mesh import make_mesh
             kh, kq = jax.random.split(jax.random.PRNGKey(0))
             U, m, n = 64, 512, 300
             h = gaussian_histogram(kh, n, U)
             Q = random_binary_queries(kq, m, U)
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             idx = ShardedIVFIndex(Q, n_shards=4, seed=0)
             cfg = MWEMConfig(T=10, mode="fast", n_records=n)
             rs = run_mwem_sharded(Q, h, cfg, jax.random.PRNGKey(5),
@@ -298,12 +299,12 @@ class TestShardedDriver:
             from repro.core.queries import (gaussian_histogram,
                                             random_binary_queries)
             from repro.mips import ShardedIVFIndex
-            from repro.launch.mesh import make_mesh_compat
+            from repro.launch.mesh import make_mesh
             kh, kq = jax.random.split(jax.random.PRNGKey(0))
             U, m, n = 32, 128, 300
             h = gaussian_histogram(kh, n, U)
             Q = random_binary_queries(kq, m, U)
-            mesh = make_mesh_compat((2, 1), ("data", "model"))
+            mesh = make_mesh((2, 1), ("data", "model"))
             cfg = MWEMConfig(T=5, mode="fast", n_records=n)
             ix_x = ShardedIVFIndex(Q, n_shards=2, seed=0, train_iters=3,
                                    use_pallas="never")
@@ -317,7 +318,7 @@ class TestShardedDriver:
             assert rx.n_scored == rp.n_scored
             assert abs(rx.final_error - rp.final_error) < 1e-6
             # model-sharded meshes silently fall back to the XLA probe
-            mesh2 = make_mesh_compat((2, 2), ("data", "model"))
+            mesh2 = make_mesh((2, 2), ("data", "model"))
             r2 = run_mwem_sharded(Q, h, cfg, jax.random.PRNGKey(3),
                                   mesh=mesh2, index=ShardedIVFIndex(
                                       Q, n_shards=2, seed=0, train_iters=3,
@@ -342,12 +343,12 @@ class TestShardedDriver:
                                             random_binary_queries)
             from repro.mips import (IVFIndex, ShardedIVFIndex,
                                     augment_complement)
-            from repro.launch.mesh import make_mesh_compat
+            from repro.launch.mesh import make_mesh
             kh, kq = jax.random.split(jax.random.PRNGKey(0))
             U, m, n = 64, 512, 300
             h = gaussian_histogram(kh, n, U)
             Q = random_binary_queries(kq, m, U)
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             idx = ShardedIVFIndex(Q, n_shards=4, seed=0)
             T = 6
             cfg = MWEMConfig(T=T, mode="fast", n_records=n, tail_cap=1)
@@ -378,12 +379,12 @@ class TestShardedDriver:
             from repro.core.queries import (gaussian_histogram,
                                             random_binary_queries)
             from repro.mips import FlatAbsIndex, ShardedIVFIndex
-            from repro.launch.mesh import make_mesh_compat
+            from repro.launch.mesh import make_mesh
             kh, kq = jax.random.split(jax.random.PRNGKey(0))
             U, m, n = 32, 256, 300
             h = gaussian_histogram(kh, n, U)
             Q = random_binary_queries(kq, m, U)
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             idx = ShardedIVFIndex(Q, n_shards=4, seed=0)
             # auto routing: >1 device + shardable workload → sharded
             assert _resolve_driver(MWEMConfig(mode="exact", n_records=n),
@@ -422,14 +423,14 @@ class TestShardedDriver:
             from repro.core.accountant import PrivacyLedger
             from repro.core.mwem import release_cost
             from repro.serve import ReleaseService
-            from repro.launch.mesh import make_mesh_compat
+            from repro.launch.mesh import make_mesh
             kh, kq = jax.random.split(jax.random.PRNGKey(0))
             U, m, n = 32, 256, 300
             from repro.core.queries import (gaussian_histogram,
                                             random_binary_queries)
             h = np.asarray(gaussian_histogram(kh, n, U))
             Q = random_binary_queries(kq, m, U)
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             cfg = MWEMConfig(eps=0.5, delta=1e-3, T=4, mode="fast")
             svc = ReleaseService(Q, cfg, wave_size=2, mesh=mesh,
                                  auto_flush=False)
@@ -469,10 +470,10 @@ class TestDryRunMachinery:
             import repro.launch.cells as cells_mod
             # monkeypatch get_config to the smoke config for a tiny compile
             import repro.configs as cfgs
-            from repro.launch.mesh import make_mesh_compat
+            from repro.launch.mesh import make_mesh
             orig = cells_mod.get_config
             cells_mod.get_config = lambda name: cfgs.get_smoke_config(name)
-            mesh = make_mesh_compat((2, 2), ("data", "model"))
+            mesh = make_mesh((2, 2), ("data", "model"))
             from repro.configs.base import SHAPES, ShapeConfig
             SHAPES["train_4k"] = ShapeConfig("train_4k", 64, 8, "train")
             cell = cells_mod.build_cell("llama3-8b", "train_4k", mesh, False)
@@ -489,8 +490,8 @@ class TestDryRunMachinery:
         out = _run("""
             import jax
             from repro.core.distributed import build_distributed_mwem_cell
-            from repro.launch.mesh import make_mesh_compat
-            mesh = make_mesh_compat((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("data", "model"))
             for mode in ("exhaustive", "lazy"):
                 fn, args, meta = build_distributed_mwem_cell(
                     mesh, False, mode=mode, m=2**14, U=2**8)
@@ -510,7 +511,7 @@ class TestMoEEP:
             from repro.models import mlp as M
             from repro.models.common import sharding_ctx, ParamBuilder
             from repro.configs.base import ShardingRules
-            from repro.launch.mesh import make_mesh_compat
+            from repro.launch.mesh import make_mesh
             cfg = get_smoke_config("qwen3-moe-30b-a3b").with_(
                 dtype="float32", moe_capacity_factor=8.0)
             pb = ParamBuilder(jax.random.PRNGKey(0), dtype=jnp.float32)
@@ -518,7 +519,7 @@ class TestMoEEP:
             p = pb.params["mlp"]
             x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
             y_dense = M.moe_mlp_dense(p, x, cfg)
-            mesh = make_mesh_compat((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rules = ShardingRules(batch="data", experts="model")
             with mesh:
                 y_ep = jax.jit(lambda p, x: M.moe_mlp_ep(p, x, cfg, mesh,

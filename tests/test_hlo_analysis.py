@@ -15,10 +15,8 @@ def _compile(fn, *args):
 
 
 def _xla_cost(compiled) -> dict:
-    """`Compiled.cost_analysis()` returns a dict on newer JAX and a
-    one-per-partition list on older releases — normalize to the dict."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
+    """XLA's own cost numbers for a single-device module."""
+    return compiled.cost_analysis()
 
 
 class TestFlops:
@@ -89,8 +87,8 @@ class TestCollectives:
         n_dev = len(jax.devices())
         if n_dev < 2:
             pytest.skip("needs >1 device (dry-run env has 512)")
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((n_dev,), ("d",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((n_dev,), ("d",))
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         def f(x):

@@ -302,6 +302,11 @@ class TestMWEMStep:
     @given(seed=st.integers(0, 10_000), c=st.integers(1, 40))
     @settings(max_examples=15, deadline=None)
     def test_aug_gather_score_bitwise(self, seed, c):
+        """The dense lazy-EM tail scores its candidates with the XLA
+        gather (`core.mwem._aug_score`), bitwise the per-row matvec."""
+        from repro.core.mwem import _aug_score
+        from repro.core.workload import as_workload
+
         rng = np.random.default_rng(seed)
         m, u = 64, 128
         Q = jnp.asarray(rng.integers(0, 2, size=(m, u)).astype(np.float32))
@@ -309,7 +314,8 @@ class TestMWEMStep:
         idx = jnp.asarray(rng.integers(0, 2 * m, size=c).astype(np.int32))
         ref = jax.jit(lambda Q, v, i: (Q[i % m] @ v)
                       * jnp.where(i < m, 1.0, -1.0))
-        got = step_ops.aug_gather_score(Q, v, idx)
+        got = jax.jit(lambda Q, v, i: _aug_score(as_workload(Q), v, i))(
+            Q, v, idx)
         np.testing.assert_array_equal(np.asarray(got),
                                       np.asarray(ref(Q, v, idx)))
 
@@ -322,6 +328,29 @@ class TestMWEMStep:
                                    jnp.float32(0.2), rule="hardt", eta=0.5)
         for a, b in zip(out_a, out_s):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+class TestRoutes:
+    """Each kernel call records the route it was traced on, so a run that
+    must have used the chip can check that nothing fell back. Shapes here
+    are used nowhere else in this file, so each call traces afresh."""
+
+    def test_interpret_route_recorded_off_tpu(self):
+        from repro.kernels import route
+        route.reset()
+        V = jnp.ones((37, 19), jnp.float32)
+        mips_topk(V, jnp.ones((19,), jnp.float32), 3)
+        want = (route.COMPILED if jax.default_backend() == "tpu"
+                else route.INTERPRET)
+        assert route.taken()["mips_topk"] == {want}
+
+    def test_shape_gate_records_ref(self):
+        from repro.kernels import route
+        route.reset()
+        lw, p, ps, rows, h = _step_state(224, seed=4)   # 224 % 128 != 0
+        step_ops.mwem_step(lw, p, ps, rows, jnp.int32(1), h,
+                           jnp.float32(0.0), rule="hardt", eta=0.5)
+        assert route.taken() == {"mwem_step": {route.REF}}
 
 
 class TestFlashAttention:

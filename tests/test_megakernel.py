@@ -183,7 +183,6 @@ class TestNoPerCallRecompilation:
                                  jnp.zeros((1,), jnp.int32), h,
                                  jnp.zeros((1,), jnp.float32),
                                  rule="hardt", eta=0.5)
-        step_ops.aug_gather_score(rows, lw, jnp.arange(8, dtype=jnp.int32))
         step_ops.mwu_apply(lw, p, ps, rows[0], h, jnp.float32(0.1),
                            rule="hardt", eta=0.5)
 
@@ -191,7 +190,7 @@ class TestNoPerCallRecompilation:
         from repro.kernels.mwem_step import ops as step_ops
 
         fns = (step_ops.mwem_step, step_ops.mwem_step_batch,
-               step_ops.aug_gather_score, step_ops.mwu_apply)
+               step_ops.mwu_apply)
         self._burn(0)
         sizes = [f._cache_size() for f in fns]
         self._burn(1)
