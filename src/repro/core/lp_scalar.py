@@ -94,6 +94,7 @@ class ScalarLPBatchResult:
     ledger: PrivacyLedger = field(default_factory=PrivacyLedger)  # per run
     ledgers: Optional[list] = None  # per-lane ledgers when the caller passed them
     telemetry: Optional[MechanismTelemetry] = None  # whole-batch aggregation
+    phase_seconds: dict = field(default_factory=dict)  # host "wait" seconds
 
 
 class _LPCalibration(NamedTuple):
@@ -440,10 +441,12 @@ def finish_lp_batch(pending: LPPendingBatch,
     if ledgers is not None and len(ledgers) != B:
         raise ValueError(f"ledgers must have one entry per lane "
                          f"({len(ledgers)} != {B})")
+    t_wait = perf_counter()
     with obs_annotate("lp_scalar/batch/finish"):
         x_bar, traces = pending.x_bar, pending.traces
         jax.block_until_ready(x_bar)
-    total = perf_counter() - pending.t0
+    t_done = perf_counter()
+    total = t_done - pending.t0
 
     viol = x_bar @ A.T - (b if batched_b else b[None, :])   # (B, m)
     violated_fracs = np.asarray(jnp.mean(viol > cfg.alpha, axis=1))
@@ -476,6 +479,7 @@ def finish_lp_batch(pending: LPPendingBatch,
         ledger=ledger,
         ledgers=list(ledgers) if ledgers is not None else None,
         telemetry=telemetry,
+        phase_seconds={"wait": t_done - t_wait},
     )
 
 

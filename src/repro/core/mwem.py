@@ -63,6 +63,7 @@ from repro.core.accountant import PrivacyLedger, calibrate_eps0
 from repro.obs.clock import perf_counter
 from repro.obs.telemetry import MechanismTelemetry, aggregate_traces, record_run
 from repro.obs.trace import annotate as obs_annotate
+from repro.obs.trace import scope as obs_scope
 from repro.core.gumbel import gumbel
 from repro.core.lazy_em import default_tail_cap, fallback_key, lazy_em_from_topk
 from repro.core.queries import max_error
@@ -141,6 +142,9 @@ class MWEMBatchResult:
     ledger: PrivacyLedger = field(default_factory=PrivacyLedger)  # per run
     ledgers: Optional[list] = None  # per-lane ledgers when the caller passed them
     telemetry: Optional[MechanismTelemetry] = None  # whole-batch aggregation
+    # host seconds of `finish_mwem_batch`'s phases ("wait", "final_error"),
+    # timed at the boundaries of its profiler spans
+    phase_seconds: dict = field(default_factory=dict)
 
     def unbatch(self) -> list:
         """Materialize one MWEMResult per batch element.
@@ -520,31 +524,41 @@ def _fused_core_waved(W: Workload, h: jax.Array, state0: MWEMState,
     batched_h = h.ndim == 2
     mwu = partial(_mwu_step, rule=rule, eta=eta, lap_scale=lap_scale)
 
+    def redo(k_sel, v):
+        with obs_scope("mwem/redo"):
+            return _exact_argmax(fallback_key(k_sel), W, v, scale)
+
     def select_one(k_sel, v, aug_idx, raw):
-        out = lazy_em_from_topk(
-            k_sel, aug_idx, raw * scale, 2 * m,
-            score_fn=lambda idx: _aug_score(W, v, idx) * scale,
-            tail_cap=tail_cap,
-            margin_slack=margin_slack * scale if margin_slack else 0.0,
-        )
+        with obs_scope("mwem/lazy_em"):
+            out = lazy_em_from_topk(
+                k_sel, aug_idx, raw * scale, 2 * m,
+                score_fn=lambda idx: _aug_score(W, v, idx) * scale,
+                tail_cap=tail_cap,
+                margin_slack=margin_slack * scale if margin_slack else 0.0,
+            )
         sel = jax.lax.cond(
             out.overflow,
-            lambda _: _exact_argmax(fallback_key(k_sel), W, v, scale),
+            lambda _: redo(k_sel, v),
             lambda _: (out.index % m).astype(jnp.int32),
             operand=None,
         )
         n_scored = jnp.where(out.overflow, jnp.int32(m), out.n_scored)
         return sel, n_scored, out.tail_count, out.overflow
 
+    def probe(v):
+        with obs_scope("mwem/probe"):
+            return batch_query_fn(v, k)         # (B, k) each
+
     def eval_ys(t, p_sum):
         err_fn = jax.vmap(partial(max_error, W),
                           in_axes=(0 if batched_h else None, 0))
-        return jax.lax.cond(
-            t % eval_every == 0,
-            lambda _: err_fn(h, p_sum / t.astype(jnp.float32)),
-            lambda _: jnp.full((B,), jnp.nan, jnp.float32),
-            operand=None,
-        )
+        with obs_scope("mwem/eval"):
+            return jax.lax.cond(
+                t % eval_every == 0,
+                lambda _: err_fn(h, p_sum / t.astype(jnp.float32)),
+                lambda _: jnp.full((B,), jnp.nan, jnp.float32),
+                operand=None,
+            )
 
     ts = jnp.arange(1, T + 1)
 
@@ -557,7 +571,7 @@ def _fused_core_waved(W: Workload, h: jax.Array, state0: MWEMState,
             state, p = carry                        # (B, U) each
             t, k_sel, k_meas = xs                   # keys (B, ...)
             v = h - p                               # (B, U)
-            aug_idx, raw = batch_query_fn(v, k)     # (B, k) each
+            aug_idx, raw = probe(v)
             sel, n_scored, tail_count, overflow = jax.vmap(select_one)(
                 k_sel, v, aug_idx, raw)
             noise = noise_fn(k_meas)                # (B,)
@@ -584,7 +598,7 @@ def _fused_core_waved(W: Workload, h: jax.Array, state0: MWEMState,
             t, k_sel, k_meas = xs                   # keys (B, ...)
             p = jax.nn.softmax(state.log_w, axis=-1)   # (B, U)
             v = h - p                                   # (B, U)
-            aug_idx, raw = batch_query_fn(v, k)         # (B, k) each
+            aug_idx, raw = probe(v)
             sel, n_scored, tail_count, overflow = jax.vmap(select_one)(
                 k_sel, v, aug_idx, raw)
             new_state = jax.vmap(mwu, in_axes=(0, 0, 0,
@@ -676,7 +690,8 @@ def _compiled_driver(entry, *args) -> Callable:
                   for x in jax.tree_util.tree_leaves(args)))
     exe = exes.get(skey)
     if exe is None:
-        exe = fn.lower(*args).compile()
+        with obs_annotate("mwem/compile"):
+            exe = fn.lower(*args).compile()
         exes[skey] = exe
     return exe
 
@@ -846,7 +861,13 @@ def finish_mwem_batch(pending: MWEMPendingBatch,
                       ledgers: Optional[list] = None) -> MWEMBatchResult:
     """Block on a launched wave and assemble its `MWEMBatchResult` — the
     finish half of `run_mwem_batch` (ledger charging, trace fetch, and
-    telemetry all happen here, after the device work lands)."""
+    telemetry all happen here, after the device work lands).
+
+    Three host spans split it: ``mwem/batch/wait`` (the block on the
+    scan), ``mwem/batch/final_error`` (dispatch of ``p_hat`` and the
+    final errors, through their host fetch) and ``mwem/batch/fetch`` (the
+    trace fetch and the ledger); the first two are timed into
+    ``phase_seconds``."""
     W, cfg, cal = pending.W, pending.cfg, pending.cal
     index, B = pending.index, pending.lanes
     h, batched_h = pending.h, pending.batched_h
@@ -854,32 +875,40 @@ def finish_mwem_batch(pending: MWEMPendingBatch,
     if ledgers is not None and len(ledgers) != B:
         raise ValueError(f"ledgers must have one entry per lane "
                          f"({len(ledgers)} != {B})")
-    with obs_annotate(f"mwem/batch/{pending.driver_label}/finish"):
-        final_state, traces = pending.final_state, pending.traces
+    t_wait = perf_counter()
+    with obs_annotate("mwem/batch/wait"):
+        final_state = pending.final_state
         jax.block_until_ready(final_state.p_sum)
-    total = perf_counter() - pending.t0
+    t_err = perf_counter()
+    total = t_err - pending.t0
 
-    p_hat = final_state.p_sum / cfg.T
-    if W.is_dense:  # pre-refactor expression, kept bitwise
-        final_errors = jnp.max(jnp.abs(_dot(h - p_hat, W.Q.T)), axis=-1)
-    else:
-        final_errors = jax.vmap(
-            lambda hh, pp: max_error(W, hh, pp),
-            in_axes=(0 if batched_h else None, 0))(h, p_hat)
+    with obs_annotate("mwem/batch/final_error"):
+        p_hat = final_state.p_sum / cfg.T
+        if W.is_dense:  # pre-refactor expression, kept bitwise
+            final_errors = jnp.max(jnp.abs(_dot(h - p_hat, W.Q.T)), axis=-1)
+        else:
+            final_errors = jax.vmap(
+                lambda hh, pp: max_error(W, hh, pp),
+                in_axes=(0 if batched_h else None, 0))(h, p_hat)
+        final_errors = np.asarray(final_errors)
+    phase_seconds = {"wait": t_err - t_wait,
+                     "final_error": perf_counter() - t_err}
 
-    ledger = PrivacyLedger()
-    if cfg.mode == "fast":
-        ledger.record_index_failure(getattr(index, "failure_mass", 1.0 / m))
-    for _ in range(cfg.T):
-        _record_iteration(ledger, cfg.mode, cfg.update_rule, cal,
-                          pending.c_idx, cfg.margin_slack)
-    if ledgers is not None:
-        for lane in ledgers:
-            if lane is not None:
-                lane.record_events(ledger.events, ledger.index_failure_mass,
-                                   ledger.approx_slack)
-
-    traces = jax.device_get(traces)
+    with obs_annotate("mwem/batch/fetch"):
+        ledger = PrivacyLedger()
+        if cfg.mode == "fast":
+            ledger.record_index_failure(getattr(index, "failure_mass",
+                                                1.0 / m))
+        for _ in range(cfg.T):
+            _record_iteration(ledger, cfg.mode, cfg.update_rule, cal,
+                              pending.c_idx, cfg.margin_slack)
+        if ledgers is not None:
+            for lane in ledgers:
+                if lane is not None:
+                    lane.record_events(ledger.events,
+                                       ledger.index_failure_mass,
+                                       ledger.approx_slack)
+        traces = jax.device_get(pending.traces)
     errors = None
     if cfg.eval_every:
         eval_ts = range(cfg.eval_every, cfg.T + 1, cfg.eval_every)
@@ -891,7 +920,7 @@ def finish_mwem_batch(pending: MWEMPendingBatch,
         total_seconds=total, amortized=True, lanes=B)
     return MWEMBatchResult(
         p_hat=p_hat,
-        final_errors=np.asarray(final_errors),
+        final_errors=final_errors,
         selected=np.asarray(traces[0]),
         n_scored=np.asarray(traces[1]),
         overflow_counts=np.asarray(traces[3]).sum(axis=1),
@@ -901,6 +930,7 @@ def finish_mwem_batch(pending: MWEMPendingBatch,
         ledger=ledger,
         ledgers=list(ledgers) if ledgers is not None else None,
         telemetry=telemetry,
+        phase_seconds=phase_seconds,
     )
 
 

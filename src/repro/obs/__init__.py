@@ -13,7 +13,7 @@ Layer map (DESIGN.md §8):
   the drivers' stacked scan traces (overflow rate, scored rows, √m
   ratio); published per run.
 * `trace` — `scope` (in-graph named_scope) / `annotate` (host-side
-  named_scope + TraceAnnotation), both gated on the obs switch.
+  TraceAnnotation with integer ids), both gated on the obs switch.
 * `events` — monotonic-stamped EventSink (elastic fail/recover, …).
 * `clock` — the single sanctioned `time` import in `src/`.
 """

@@ -6,10 +6,15 @@ Two annotation flavors, matching where the code runs:
   functions / scan bodies. Attaches the name to the emitted HLO ops so
   XLA profiler timelines line up with logical phases (kernel call sites
   in `kernels/*/ops.py`). Pure metadata: cannot change numerics.
-* `annotate(name)` — **host-side**: `jax.named_scope` *plus*
-  `jax.profiler.TraceAnnotation`, for driver dispatch and wave
-  execution on the host. TraceAnnotation shows up on the host timeline
-  when a profiler session is active and is a no-op otherwise.
+* `annotate(name, **ids)` — **host-side**: `jax.profiler.TraceAnnotation`
+  alone, for the release path's layer boundaries (wave launch, wait,
+  final error, delivery, ledger, WAL). Its events land on the profile's
+  host plane, on the device trace's clock, when a profiler session is
+  active; otherwise it costs one `TraceMe` construction. ``ids`` are
+  integers (``wave``, ``ticket``, ``lanes``), kept as the event's stats:
+  the profiler cuts a string value at its first comma, so never pass
+  one. It enters no `jax.named_scope`, which would stamp its name onto
+  whatever is traced or compiled under the span.
 
 Both collapse to `nullcontext()` when obs is disabled. Neither path
 touches the key chain or any traced value, so enabled-vs-disabled
@@ -63,12 +68,8 @@ def scope(name: str) -> ContextManager:
     return jax.named_scope(name)
 
 
-def annotate(name: str) -> ContextManager:
-    """Host-side phase marker: named scope + profiler TraceAnnotation."""
-    if not _enabled:
+def annotate(name: str, **ids: int) -> ContextManager:
+    """Host-side span: a profiler TraceAnnotation with integer ``ids``."""
+    if not _enabled or _TraceAnnotation is None:
         return contextlib.nullcontext()
-    stack = contextlib.ExitStack()
-    stack.enter_context(jax.named_scope(name))
-    if _TraceAnnotation is not None:
-        stack.enter_context(_TraceAnnotation(name))
-    return stack
+    return _TraceAnnotation(name, **ids)

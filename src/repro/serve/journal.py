@@ -68,10 +68,12 @@ class Journal:
         fault_site("journal.append")
         # seq/kind are authoritative — a payload key can never shadow them
         rec = {**payload, "seq": self._seq, "kind": rec_kind}
-        self._fh.write(json.dumps(rec, sort_keys=True) + "\n")
-        self._fh.flush()
-        if self._fsync:
-            os.fsync(self._fh.fileno())
+        with obs.annotate("serve/journal/append"):
+            self._fh.write(json.dumps(rec, sort_keys=True) + "\n")
+            self._fh.flush()
+            if self._fsync:
+                with obs.annotate("serve/journal/fsync"):
+                    os.fsync(self._fh.fileno())
         self._seq += 1
         return rec
 

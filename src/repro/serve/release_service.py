@@ -94,7 +94,7 @@ from repro.mips import (FlatAbsIndex, FlatIndex, IVFIndex, LSHIndex,
                         MarginalIVFIndex, ShardedIVFIndex,
                         augment_complement, lp_scalar_rows)
 from repro.obs import trace as obs
-from repro.obs.clock import monotonic, sleep
+from repro.obs.clock import monotonic, perf_counter, sleep
 from repro.obs.metrics import MetricsRegistry, default_registry
 from repro.serve.admission import AdmissionController, AdmissionDecision
 from repro.serve.breaker import CircuitBreaker
@@ -196,6 +196,7 @@ class _InflightWave:
     pending: object                  # MWEMPendingBatch | LPPendingBatch
     decision: WaveDecision
     attempt: int
+    wave: int                        # launch number, from 0 (profiler id)
 
 
 class ReleaseService:
@@ -283,6 +284,7 @@ class ReleaseService:
                         if self.streaming else None))
         self.wave_log: List[WaveDecision] = []
         self._inflight: Optional[_InflightWave] = None
+        self._next_wave = 0
         self.degraded = False
         self.breaker = CircuitBreaker(threshold=breaker_threshold,
                                       registry=self.metrics)
@@ -532,18 +534,19 @@ class ReleaseService:
         process dies in between, recovery's in-doubt rule (dispatched, no
         resolution ⇒ committed) reconstructs the same ledger state."""
         sess = self.sessions[ticket.tenant_id]
-        for attempt in range(self.retry_limit + 1):
-            try:
-                sess.ledger.commit(ticket.rid)
-                break
-            except KeyError:
-                raise
-            except Exception as exc:
-                if not _retryable(exc) or attempt >= self.retry_limit:
+        with obs.annotate("serve/ledger/commit", ticket=ticket.ticket_id):
+            for attempt in range(self.retry_limit + 1):
+                try:
+                    sess.ledger.commit(ticket.rid)
+                    break
+                except KeyError:
                     raise
-                self._backoff(attempt)
-        rid, ticket.rid = ticket.rid, None
-        self._journal("committed", tenant_id=ticket.tenant_id, rid=rid)
+                except Exception as exc:
+                    if not _retryable(exc) or attempt >= self.retry_limit:
+                        raise
+                    self._backoff(attempt)
+            rid, ticket.rid = ticket.rid, None
+            self._journal("committed", tenant_id=ticket.tenant_id, rid=rid)
 
     def _note_dispatch_failure(self, exc: BaseException,
                                wave: List[ReleaseTicket], attempt: int,
@@ -673,26 +676,27 @@ class ReleaseService:
         if shed is not None:
             return shed
         sess = self.sessions[tenant_id]
-        cfg = self._group_cfg(sess.n_records)
-        bundle = release_cost(cfg, self.m, self.U, index=self.index)
-        decision = self.admission.check(sess, bundle,
-                                        reserved=self._reserved(tenant_id))
-        ticket = ReleaseTicket(
-            ticket_id=self._next_ticket, tenant_id=tenant_id,
-            seed=self._take_seed(seed),
-            status="queued" if decision.admitted else "rejected",
-            decision=decision, cost_bundle=bundle,
-            submit_time=monotonic(),
-        )
-        self._next_ticket += 1
-        if not decision.admitted:
-            sess.rejected_count += 1
-            self.stats.rejected += 1
-            if obs.enabled():
-                self.metrics.counter("admission_rejections_total",
-                                     kind="mwem", tenant=tenant_id).inc()
-            return ticket
-        ticket.rid = sess.ledger.reserve(*bundle)
+        with obs.annotate("serve/admit", ticket=self._next_ticket):
+            cfg = self._group_cfg(sess.n_records)
+            bundle = release_cost(cfg, self.m, self.U, index=self.index)
+            decision = self.admission.check(
+                sess, bundle, reserved=self._reserved(tenant_id))
+            ticket = ReleaseTicket(
+                ticket_id=self._next_ticket, tenant_id=tenant_id,
+                seed=self._take_seed(seed),
+                status="queued" if decision.admitted else "rejected",
+                decision=decision, cost_bundle=bundle,
+                submit_time=monotonic(),
+            )
+            self._next_ticket += 1
+            if not decision.admitted:
+                sess.rejected_count += 1
+                self.stats.rejected += 1
+                if obs.enabled():
+                    self.metrics.counter("admission_rejections_total",
+                                         kind="mwem", tenant=tenant_id).inc()
+                return ticket
+            ticket.rid = sess.ledger.reserve(*bundle)
         d = deadline if deadline is not None else self.default_deadline
         if d is not None:
             ticket.deadline = ticket.submit_time + d
@@ -1001,8 +1005,10 @@ class ReleaseService:
                           occupancy=decision.occupancy)
             self.wave_log.append(WaveDecision(True, decision.reason, size,
                                               decision.occupancy))
+            wave_no, self._next_wave = self._next_wave, self._next_wave + 1
             try:
-                with obs.annotate(f"serve/wave/{kind}/stream"):
+                with obs.annotate(f"serve/wave/{kind}/launch", wave=wave_no,
+                                  lanes=size):
                     fault_site("wave.dispatch")
                     if kind == "lp":
                         pending = launch_lp_batch(self.lp.A, self.lp.b,
@@ -1033,7 +1039,7 @@ class ReleaseService:
                                  decision=WaveDecision(
                                      True, decision.reason, size,
                                      decision.occupancy),
-                                 attempt=attempt)
+                                 attempt=attempt, wave=wave_no)
 
     def _inflight_ready(self) -> bool:
         """Whether the in-flight wave's device work has landed (so
@@ -1063,7 +1069,8 @@ class ReleaseService:
         loop."""
         while True:
             try:
-                with obs.annotate(f"serve/wave/{fl.kind}/finish"):
+                with obs.annotate(f"serve/wave/{fl.kind}/finish",
+                                  wave=fl.wave):
                     if fl.kind == "lp":
                         result = finish_lp_batch(fl.pending)
                     else:
@@ -1096,15 +1103,23 @@ class ReleaseService:
                                      kind=fl.kind).inc(saved)
         self._record_wave_metrics(fl.kind, len(fl.tickets), fl.n_pad,
                                   lanes=fl.size)
+        for phase, seconds in result.phase_seconds.items():
+            self._record_wave_phase(fl, phase, seconds)
+        deliver = self._deliver_lp if fl.kind == "lp" else self._deliver_mwem
+        t0 = perf_counter()
+        with obs.annotate(f"serve/wave/{fl.kind}/deliver", wave=fl.wave):
+            done = deliver(fl.tickets, result, trigger=fl.decision.reason)
+        self._record_wave_phase(fl, "deliver", perf_counter() - t0)
+        return done
+
+    def _record_wave_phase(self, fl: _InflightWave, phase: str,
+                           seconds: float) -> None:
+        """One phase of a resolved streaming wave (``wait``,
+        ``final_error``, ``deliver``), timed at its profiler span's
+        boundaries, for operators who run no profiler."""
         if obs.enabled():
-            self.metrics.histogram("wave_latency_seconds", kind=fl.kind,
-                                   lanes=fl.size).observe(
-                                       result.total_seconds)
-        if fl.kind == "lp":
-            return self._deliver_lp(fl.tickets, result,
-                                    trigger=fl.decision.reason)
-        return self._deliver_mwem(fl.tickets, result,
-                                  trigger=fl.decision.reason)
+            self.metrics.histogram("wave_phase_seconds", kind=fl.kind,
+                                   lanes=fl.size, phase=phase).observe(seconds)
 
     def _lane_cost(self, sess: TenantSession, snap, per_run: PrivacyLedger,
                    k: int) -> tuple:
@@ -1346,8 +1361,10 @@ class ReleaseService:
                 self._commit_ticket(ticket)
                 k = lanes_seen.get(ticket.tenant_id, 0)
                 lanes_seen[ticket.tenant_id] = k + 1
-                eps_cost, delta_cost = self._lane_cost(
-                    sess, snaps[ticket.tenant_id], result.ledger, k)
+                with obs.annotate("serve/ledger/lane_cost",
+                                  ticket=ticket.ticket_id):
+                    eps_cost, delta_cost = self._lane_cost(
+                        sess, snaps[ticket.tenant_id], result.ledger, k)
                 rel = ReleasedHistogram(
                     release_id=self._next_release,
                     p_hat=p_hat[i],
@@ -1385,7 +1402,8 @@ class ReleaseService:
         """Answer a linear query from the tenant's released histogram(s) —
         post-processing, zero additional ε; repeats served from the cache."""
         t0 = monotonic()
-        ans = self.sessions[tenant_id].answer(q, release_id=release_id)
+        with obs.annotate("serve/answer"):
+            ans = self.sessions[tenant_id].answer(q, release_id=release_id)
         self._record_answer(ans, t0)
         return ans
 

@@ -7,6 +7,7 @@ identical — the obs layer only ever reads traces the drivers already
 return and attaches pure-metadata profiler scopes.
 """
 
+import glob
 import json
 import math
 import os
@@ -19,8 +20,9 @@ import numpy as np
 import pytest
 
 from repro.core import MWEMConfig, run_mwem, run_mwem_batch, run_mwem_fused
+from repro.core.mwem import aot_compile_batch
 from repro.core.queries import gaussian_histogram, random_binary_queries
-from repro.mips import FlatAbsIndex
+from repro.mips import FlatAbsIndex, IVFIndex, augment_complement
 from repro.obs import trace as obs_trace
 from repro.obs.events import EventSink
 from repro.obs.metrics import (GROWTH, Histogram, MetricsRegistry,
@@ -38,6 +40,67 @@ def workload():
     h = gaussian_histogram(kh, n, U)
     Q = random_binary_queries(kq, m, U)
     return Q, h, n
+
+
+WINDOW = "test/window"
+WAVE_SPANS = ("serve/wave/mwem/launch", "serve/wave/mwem/finish",
+              "serve/wave/mwem/deliver")
+STREAM_SPANS = WAVE_SPANS + ("mwem/batch/wait", "mwem/batch/final_error")
+
+
+def stream_releases(workload, journal=None):
+    """Four releases of two tenants in streaming waves of two: each lane's
+    p_hat and selections, and the tenants' ledgers."""
+    from repro.serve import ReleaseService
+
+    Q, h, n = workload
+    svc = ReleaseService(Q, MWEMConfig(eps=0.5, delta=1e-3, T=4,
+                                       mode="fast"),
+                         wave_size=2, streaming=True, auto_flush=False,
+                         registry=MetricsRegistry(), journal=journal)
+    for t in ("a", "b"):
+        svc.create_session(t, eps_budget=50.0, delta_budget=0.5,
+                           h=np.asarray(h), n_records=n)
+    selected = {}
+    deliver = svc._deliver_mwem
+
+    def tap(wave, result, trigger=None):
+        for i, t in enumerate(wave):
+            selected[t.ticket_id] = np.asarray(result.selected[i])
+        return deliver(wave, result, trigger=trigger)
+
+    svc._deliver_mwem = tap
+    tickets = [svc.submit("ab"[i % 2], seed=20 + i) for i in range(4)]
+    svc.pump()
+    svc.flush()
+    assert all(t.status == "done" for t in tickets)
+    return ([np.asarray(t.release.p_hat) for t in tickets],
+            [selected[t.ticket_id] for t in tickets],
+            {t: svc.sessions[t].ledger for t in ("a", "b")})
+
+
+def profiled(tmp_path, fn):
+    """``fn()`` under a profiler session, inside a window mark; returns
+    its result and the events of the host plane holding the mark, each as
+    (name, start_ns, end_ns, stats); stats are read for program spans
+    only."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation(WINDOW):
+            out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    for plane in ProfileData.from_file(path).planes:
+        events = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                   dict(e.stats) if e.name.startswith(("serve/", "mwem/"))
+                   else {}) for line in plane.lines for e in line.events]
+        if any(name == WINDOW for name, *_ in events):
+            return out, events
+    raise AssertionError("no plane holds the window mark")
 
 
 @pytest.fixture(autouse=True)
@@ -266,6 +329,23 @@ class TestBitwiseParity:
         self._pair(lambda: run_mwem(Q, h, cfg, jax.random.PRNGKey(3),
                                     index=index))
 
+    @pytest.mark.parametrize("switch", ["obs", "profiler"])
+    def test_streaming_release(self, workload, switch, tmp_path):
+        """A streaming release through the service: obs on vs off, and a
+        profiler session on vs off, give the same lanes and ledgers."""
+        if switch == "obs":
+            on = stream_releases(workload)
+            with obs_trace.disabled():
+                off = stream_releases(workload)
+        else:
+            on, _ = profiled(tmp_path, lambda: stream_releases(workload))
+            off = stream_releases(workload)
+        for a, b in zip(on[0], off[0]):
+            assert a.tobytes() == b.tobytes()
+        for a, b in zip(on[1], off[1]):
+            assert np.array_equal(a, b)
+        assert on[2] == off[2]
+
     @pytest.mark.parametrize("mode", ["exact", "fast"])
     def test_sharded(self, workload, mode):
         from repro.core.distributed import run_mwem_sharded
@@ -276,6 +356,78 @@ class TestBitwiseParity:
         index = None  # fast mode builds ShardedIVFIndex(Q, n_shards=1)
         self._pair(lambda: run_mwem_sharded(Q, h, cfg, jax.random.PRNGKey(3),
                                             index=index))
+
+
+class TestProgramSpans:
+    """The release path's host spans (`obs.annotate`) on a CPU profile:
+    on the window mark's host plane, nested as the code nests them, with
+    integer ids as stats."""
+
+    def test_streaming_release_records_its_spans(self, workload, tmp_path):
+        from repro.serve.journal import Journal
+
+        journal = Journal(str(tmp_path / "wal.jsonl"), fsync=True)
+        _, events = profiled(tmp_path / "trace",
+                             lambda: stream_releases(workload, journal))
+        by = {}
+        for name, a, b, stats in events:
+            by.setdefault(name, []).append((a, b, stats))
+        for name in STREAM_SPANS + ("serve/admit", "serve/ledger/commit",
+                                    "serve/ledger/lane_cost",
+                                    "serve/journal/append",
+                                    "serve/journal/fsync"):
+            assert name in by, name
+
+        def inside(name, a, b):
+            return [s for s in by[name] if a <= s[0] and s[1] <= b]
+
+        launches = {s["wave"]: (a, b, s)
+                    for a, b, s in by["serve/wave/mwem/launch"]}
+        assert sorted(launches) == [0, 1]
+        assert all(s["lanes"] == 2 for _, _, s in launches.values())
+        delivers = {s["wave"]: (a, b) for a, b, s in
+                    by["serve/wave/mwem/deliver"]}
+        for a, b, stats in by["serve/wave/mwem/finish"]:
+            w = stats["wave"]
+            assert launches[w][1] <= a           # launched before it resolves
+            assert len(inside("mwem/batch/wait", a, b)) == 1
+            assert len(inside("mwem/batch/final_error", a, b)) == 1
+            wait, = inside("mwem/batch/wait", a, b)
+            err, = inside("mwem/batch/final_error", a, b)
+            assert wait[1] <= err[0]
+            d0, d1 = delivers[w]
+            assert b <= d0                       # phase two follows
+            assert len(inside("serve/ledger/commit", d0, d1)) == 2
+            assert len(inside("serve/ledger/lane_cost", d0, d1)) == 2
+        assert len(by["serve/admit"]) == 4
+        assert sorted(s["ticket"] for _, _, s in by["serve/admit"]) == \
+            [0, 1, 2, 3]
+        for a, b, _ in by["serve/journal/fsync"]:
+            assert any(x <= a and b <= y
+                       for x, y, _ in by["serve/journal/append"])
+
+    def test_disabled_records_none(self, workload, tmp_path):
+        with obs_trace.disabled():
+            _, events = profiled(tmp_path, lambda: stream_releases(workload))
+        names = {name for name, *_ in events}
+        assert not {n for n in names if n.startswith(("serve/", "mwem/"))}
+
+    def test_wave_hlo_carries_the_in_graph_scopes(self, workload):
+        """The waved core's four scopes reach the compiled wave's HLO as
+        ``op_name`` metadata (pure metadata: names, not numerics)."""
+        import re
+
+        Q, _, n = workload
+        ivf = IVFIndex(augment_complement(np.asarray(Q)), seed=0,
+                       train_iters=3, use_pallas="never")
+        cfg = MWEMConfig(T=4, mode="fast", n_records=n, eval_every=2)
+        aot_compile_batch(Q, cfg, 2, index=ivf)
+        exes = [exe for _, cached in ivf._fused_driver_cache.values()
+                for exe in cached.values()]
+        assert len(exes) == 1
+        names = set(re.findall(r'op_name="([^"]*)"', exes[0].as_text()))
+        for path in ("mwem/probe", "mwem/lazy_em", "mwem/redo", "mwem/eval"):
+            assert any(path in name for name in names), path
 
 
 class TestLedgerGauges:

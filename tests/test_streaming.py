@@ -472,8 +472,8 @@ class TestStreamingService:
 
     def test_coalescer_obs_series(self, workload):
         """Satellite 4's obs assertions: the occupancy gauge and trigger
-        counter publish per kind, per-wave-size latency histograms key by
-        executed lane count, and `admission_to_answer_seconds` splits by
+        counter publish per kind, per-wave-size phase histograms (wait,
+        final error, delivery) key by executed lane count, and `admission_to_answer_seconds` splits by
         trigger reason on its own series — the plain per-kind series the
         batch path populates keeps its identity."""
         Q, h = workload
@@ -490,8 +490,12 @@ class TestStreamingService:
         assert "admission_to_answer_seconds{kind=mwem,trigger=full}" in hists
         assert ("admission_to_answer_seconds{kind=mwem,trigger=flush}"
                 in hists)
-        assert "wave_latency_seconds{kind=mwem,lanes=4}" in hists
-        assert "wave_latency_seconds{kind=mwem,lanes=2}" in hists
+        # one observation per phase of each resolved wave, keyed by lanes
+        for lanes in (4, 2):
+            for phase in ("wait", "final_error", "deliver"):
+                key = (f"wave_phase_seconds{{kind=mwem,lanes={lanes},"
+                       f"phase={phase}}}")
+                assert hists[key]["count"] == 1, key
         assert counters["wave_trigger_total{kind=mwem,reason=full}"] >= 1
         assert counters["wave_trigger_total{kind=mwem,reason=flush}"] >= 1
         assert "coalescer_occupancy{kind=mwem}" in snap["gauges"]
