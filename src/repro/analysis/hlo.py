@@ -32,6 +32,9 @@ _DTYPE_BYTES = {
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COMMENT_RE = re.compile(r"/\*.*?\*/")
+# a shape's layout, e.g. `{1,0:T(8,128)S(1)}` on TPU: its tiles read as
+# `word(` to `_OP_RE`, so it is dropped before an op line is matched
+_LAYOUT_RE = re.compile(r"(?<=\])\{[^{}]*\}")
 # `%name = <type> opcode(...)` — the type may be a tuple; the opcode is the
 # first `word(` token (tuple-opening parens are preceded by whitespace).
 _OP_RE = re.compile(
@@ -96,7 +99,7 @@ def _parse_computations(text: str):
     entry = None
     current = None
     for line in text.splitlines():
-        stripped = _COMMENT_RE.sub("", line).strip()
+        stripped = _LAYOUT_RE.sub("", _COMMENT_RE.sub("", line)).strip()
         if not stripped:
             continue
         # computation headers end with "{", contain "->", and are not ops

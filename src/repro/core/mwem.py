@@ -505,8 +505,10 @@ def _fused_core_waved(W: Workload, h: jax.Array, state0: MWEMState,
     lanes and hands the stacked (B, U) probe block to
     ``index.query_in_graph_batch`` — on the kernel route, cells probed by
     several lanes stream from HBM once and scoring is MXU-batched.
-    Everything after the probe (LazyEM, overflow fallback, MW update) is
-    the vmapped per-lane math of `_fused_core`, and the key chain is the
+    Everything after the probe (LazyEM, MW update) is the vmapped
+    per-lane math of `_fused_core`; the overflow redo runs under one
+    wave-level `cond`, only on iterations where a lane overflowed
+    (`select` below). The key chain is the
     per-lane `split_chain`, so lane b reproduces `run_mwem_fused(key_b)`
     (same trace fields, same ledger path; bitwise when the batched probe
     equals the per-lane probe — exactly true on the XLA route, up to exact
@@ -528,18 +530,30 @@ def _fused_core_waved(W: Workload, h: jax.Array, state0: MWEMState,
         with obs_scope("mwem/redo"):
             return _exact_argmax(fallback_key(k_sel), W, v, scale)
 
-    def select_one(k_sel, v, aug_idx, raw):
+    def lazy_one(k_sel, v, aug_idx, raw):
         with obs_scope("mwem/lazy_em"):
-            out = lazy_em_from_topk(
+            return lazy_em_from_topk(
                 k_sel, aug_idx, raw * scale, 2 * m,
                 score_fn=lambda idx: _aug_score(W, v, idx) * scale,
                 tail_cap=tail_cap,
                 margin_slack=margin_slack * scale if margin_slack else 0.0,
             )
+
+    def select(k_sel, v, aug_idx, raw):
+        """Per-lane lazy EM; the exhaustive redo under a wave-level `cond`.
+
+        A per-lane `cond` under `vmap` becomes a `select` that runs the
+        Θ(mU) redo for every lane at every iteration. Its predicate here
+        is the unbatched ``any(overflow)``, so the redo executes only on
+        iterations where some lane overflowed, and each lane keeps its
+        lazy draw unless it overflowed itself — the same per-lane result
+        as the vmapped cond (DESIGN.md §2)."""
+        out = jax.vmap(lazy_one)(k_sel, v, aug_idx, raw)
+        lazy = (out.index % m).astype(jnp.int32)
         sel = jax.lax.cond(
-            out.overflow,
-            lambda _: redo(k_sel, v),
-            lambda _: (out.index % m).astype(jnp.int32),
+            jnp.any(out.overflow),
+            lambda _: jnp.where(out.overflow, jax.vmap(redo)(k_sel, v), lazy),
+            lambda _: lazy,
             operand=None,
         )
         n_scored = jnp.where(out.overflow, jnp.int32(m), out.n_scored)
@@ -572,8 +586,8 @@ def _fused_core_waved(W: Workload, h: jax.Array, state0: MWEMState,
             t, k_sel, k_meas = xs                   # keys (B, ...)
             v = h - p                               # (B, U)
             aug_idx, raw = probe(v)
-            sel, n_scored, tail_count, overflow = jax.vmap(select_one)(
-                k_sel, v, aug_idx, raw)
+            sel, n_scored, tail_count, overflow = select(k_sel, v, aug_idx,
+                                                         raw)
             noise = noise_fn(k_meas)                # (B,)
             if kernel and W.is_dense:
                 lw, p_new, ps = step_ops.mwem_step_batch(
@@ -599,8 +613,8 @@ def _fused_core_waved(W: Workload, h: jax.Array, state0: MWEMState,
             p = jax.nn.softmax(state.log_w, axis=-1)   # (B, U)
             v = h - p                                   # (B, U)
             aug_idx, raw = probe(v)
-            sel, n_scored, tail_count, overflow = jax.vmap(select_one)(
-                k_sel, v, aug_idx, raw)
+            sel, n_scored, tail_count, overflow = select(k_sel, v, aug_idx,
+                                                         raw)
             new_state = jax.vmap(mwu, in_axes=(0, 0, 0,
                                                0 if batched_h else None,
                                                0))(state, p, W.rows(sel), h,
@@ -913,17 +927,21 @@ def finish_mwem_batch(pending: MWEMPendingBatch,
     if cfg.eval_every:
         eval_ts = range(cfg.eval_every, cfg.T + 1, cfg.eval_every)
         errors = np.asarray(traces[4])[:, [t - 1 for t in eval_ts]]
+    overflow = np.asarray(traces[3])                    # (B, T)
     telemetry = record_run(
         workload="mwem", driver=pending.driver_label, mode=cfg.mode, m=m,
         n_scored=np.asarray(traces[1]),
-        overflow_count=int(np.asarray(traces[3]).sum()),
-        total_seconds=total, amortized=True, lanes=B)
+        overflow_count=int(overflow.sum()),
+        total_seconds=total, amortized=True, lanes=B,
+        # the waved core's redo runs at iterations where any lane overflowed
+        wave_redo_count=(int(overflow.any(axis=0).sum())
+                         if pending.driver_label == "waved" else None))
     return MWEMBatchResult(
         p_hat=p_hat,
         final_errors=final_errors,
         selected=np.asarray(traces[0]),
         n_scored=np.asarray(traces[1]),
-        overflow_counts=np.asarray(traces[3]).sum(axis=1),
+        overflow_counts=overflow.sum(axis=1),
         errors=errors,
         eval_every=cfg.eval_every,
         total_seconds=total,
@@ -1001,13 +1019,12 @@ def run_mwem_batch(
     probed by several lanes once — DESIGN.md §3). Per-lane parity with
     `run_mwem_fused` is bitwise on the XLA probe route; the TPU batch
     kernel's slot ordering can break *exact* score ties differently than
-    a standalone probe (kernels/ivf_probe/ref.py). Cost caveat: under
-    either route the
-    overflow-fallback `lax.cond` lowers to a select that executes both
-    branches every iteration, so for probe-only indices (IVF/LSH) each
-    batched iteration pays the Θ(mU) exhaustive branch — batch those
-    through a Python loop over `run_mwem` if selection cost matters more
-    than dispatch (DESIGN.md §2).
+    a standalone probe (kernels/ivf_probe/ref.py). Cost of the overflow
+    fallback: the waved route runs the Θ(mU) exhaustive redo only on
+    iterations where some lane overflowed (one wave-level `lax.cond`;
+    the telemetry's ``wave_redo_count`` counts them). The
+    `vmap(_fused_core)` route still lowers its per-lane `lax.cond` to a
+    select that executes both branches every iteration (DESIGN.md §2).
     """
     if cfg.driver == "host":
         raise ValueError("run_mwem_batch always uses the fused driver; "

@@ -11,7 +11,10 @@ turns that free data into the numbers the paper's claim is about:
 * `lazy_fraction` — fraction of iterations resolved without scoring
   the full candidate set;
 * `sqrt_m_ratio` — mean scored rows ÷ √m: ~O(1) when the sublinear
-  claim holds, → √m when every iteration degenerates to exhaustive.
+  claim holds, → √m when every iteration degenerates to exhaustive;
+* `wave_redo_count` (waved driver only) — scan iterations at which the
+  wave's exhaustive redo ran: those where any lane overflowed. Published
+  as `mechanism_wave_redo_total` and, ÷ T, `mechanism_wave_redo_rate`.
 
 `aggregate_traces` is pure (no registry side effects, always runs, so
 the `telemetry` record on results exists even with obs disabled —
@@ -51,6 +54,13 @@ class MechanismTelemetry:
     sqrt_m_ratio: float  # n_scored_mean / sqrt(m)
     total_seconds: float
     amortized: bool  # True when total_seconds covers >1 lane / whole scan
+    wave_redo_count: Optional[int] = None  # waved driver: iterations redone
+
+    @property
+    def wave_redo_rate(self) -> Optional[float]:
+        if self.wave_redo_count is None:
+            return None
+        return self.wave_redo_count / self.T if self.T else 0.0
 
     def as_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -67,6 +77,7 @@ def aggregate_traces(
     total_seconds: float,
     amortized: bool,
     lanes: int = 1,
+    wave_redo_count: Optional[int] = None,
 ) -> MechanismTelemetry:
     """Fold stacked per-iteration traces into one `MechanismTelemetry`.
 
@@ -95,6 +106,8 @@ def aggregate_traces(
         sqrt_m_ratio=mean / math.sqrt(m) if m > 0 else 0.0,
         total_seconds=float(total_seconds),
         amortized=bool(amortized),
+        wave_redo_count=(None if wave_redo_count is None
+                         else int(wave_redo_count)),
     )
 
 
@@ -117,6 +130,10 @@ def publish(
         tel.n_scored_mean
     )
     reg.histogram("mechanism_run_seconds", **labels).observe(tel.total_seconds)
+    if tel.wave_redo_count is not None:
+        reg.counter("mechanism_wave_redo_total", **labels).inc(
+            tel.wave_redo_count)
+        reg.gauge("mechanism_wave_redo_rate", **labels).set(tel.wave_redo_rate)
     return tel
 
 
@@ -131,6 +148,7 @@ def record_run(
     total_seconds: float,
     amortized: bool,
     lanes: int = 1,
+    wave_redo_count: Optional[int] = None,
     registry: Optional[MetricsRegistry] = None,
 ) -> MechanismTelemetry:
     """aggregate_traces + publish in one call — the driver-side entry point."""
@@ -144,5 +162,6 @@ def record_run(
         total_seconds=total_seconds,
         amortized=amortized,
         lanes=lanes,
+        wave_redo_count=wave_redo_count,
     )
     return publish(tel, registry=registry)

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.hlo import (_callees, _operand_names,
+                                _parse_computations, _shape_dims)
 from repro.core.mwem import (MWEMConfig, MWEMState, _calibrate,
                              _fused_driver, _fused_statics)
 from repro.core.workload import DenseWorkload, MarginalWorkload
@@ -139,6 +141,38 @@ def _largest_constant(hlo_text: str) -> int:
                 for ds in dims), default=0)
 
 
+def _table_contractions(hlo_text: str, dims: tuple) -> list:
+    """Where each contraction over a ``dims``-shaped table runs, one entry
+    per contraction: "branch" when the first loop body or conditional
+    branch that encloses it (up through fusions and calls) is a branch
+    computation, "body" when it is a while body. An operand whose trailing
+    dims are ``dims`` counts too: a per-lane `cond` under `vmap` contracts
+    a lane-broadcast copy of the table."""
+    comps, _ = _parse_computations(hlo_text)
+    callers = {}
+    for name, ops in comps.items():
+        for op in ops:
+            for attr, callee in _callees(op):
+                callers.setdefault(callee, []).append((attr, name))
+
+    def places(comp):
+        out = []
+        for attr, parent in callers.get(comp, []):
+            out += ([attr] if attr in ("branch", "body")
+                    else places(parent))
+        return out
+
+    found = []
+    for name, ops in comps.items():
+        shapes = {op.name: _shape_dims(op.type_str)[1] for op in ops}
+        for op in ops:
+            if (op.opcode in ("dot", "convolution")
+                    and any(shapes.get(o, ())[-len(dims):] == dims
+                            for o in _operand_names(op))):
+                found += places(name)
+    return found
+
+
 # the smoke's IVF wave: 2·M augmented rows (nlist 362, cap 182 — 184 in the
 # cell-grouped layout), nprobe 10
 IVF_TABLES = {"_v": ((2 * M, U),), "_cents": ((NLIST, U),),
@@ -185,3 +219,9 @@ def test_wave_driver_compiles_for_v5e(kind, lanes, one_chip, monkeypatch):
                   for a in jax.tree_util.tree_leaves(state))
     assert compiled.memory_analysis().argument_size_in_bytes >= largest
     assert set(_smoke_module().pallas_calls(text)) == want
+    # the exhaustive overflow redo (Q @ v for every lane) runs only inside
+    # the wave-level conditional's branch; the only contraction over Q left
+    # in the loop body is the flat wave's probe
+    where = _table_contractions(text, (M, U))
+    assert "branch" in where
+    assert where.count("body") == (1 if kind == "flat" else 0)

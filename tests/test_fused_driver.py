@@ -15,6 +15,8 @@ from repro.core import MWEMConfig, run_mwem, run_mwem_batch, run_mwem_fused
 from repro.core.accountant import PrivacyLedger
 from repro.core.queries import gaussian_histogram, max_error, random_binary_queries
 from repro.mips import FlatAbsIndex, NSWIndex, augment_complement
+from repro.obs import trace as obs_trace
+from repro.obs.metrics import default_registry
 
 
 @pytest.fixture(scope="module")
@@ -199,6 +201,47 @@ class TestKernelizedProbe:
                                     index=ivf_xla)
             assert list(batch.selected[b]) == single.selected
             assert list(batch.n_scored[b]) == single.n_scored
+
+    @pytest.mark.parametrize("use_pallas", ["auto", "never"])
+    def test_waved_redo_only_where_a_lane_overflowed(self, workload,
+                                                     ivf_indices, use_pallas):
+        """The waved core runs the exhaustive redo under one wave-level
+        `cond` on any(overflow). A tail_cap of 12 gives iterations where
+        some lanes overflow and others do not, and iterations where none
+        does: every lane must still equal its standalone fused run bit
+        for bit, in both scan bodies ("auto": the carried-density step,
+        "never": the classic body), and the redo counter must count the
+        iterations with any overflow."""
+        ivf_xla, _ = ivf_indices
+        Q, h, n = workload
+        m, B, T = Q.shape[0], 4, 12
+        cfg = MWEMConfig(T=T, mode="fast", n_records=n, tail_cap=12,
+                         use_pallas=use_pallas)
+        keys = jnp.stack([jax.random.PRNGKey(s) for s in range(B)])
+        series = ("mechanism_wave_redo_total"
+                  "{driver=waved,mode=fast,workload=mwem}")
+        before = default_registry().snapshot()["counters"].get(series, 0.0)
+        batch = run_mwem_batch(Q, h, cfg, keys, index=ivf_xla)
+        after = default_registry().snapshot()["counters"].get(series, 0.0)
+        for b in range(B):
+            single = run_mwem_fused(Q, h, cfg, jax.random.PRNGKey(b),
+                                    index=ivf_xla)
+            assert list(batch.selected[b]) == single.selected
+            assert list(batch.n_scored[b]) == single.n_scored
+            assert int(batch.overflow_counts[b]) == single.overflow_count
+        # an overflowed step scores all m rows, a lazy one fewer
+        over = np.asarray(batch.n_scored) == m                  # (B, T)
+        np.testing.assert_array_equal(over.sum(axis=1),
+                                      batch.overflow_counts)
+        redone = over.any(axis=0)
+        assert (redone & ~over.all(axis=0)).any()    # mixed iterations
+        assert (~redone).any()                       # iterations with none
+        tel = batch.telemetry
+        assert tel.driver == "waved"
+        assert tel.wave_redo_count == int(redone.sum())
+        assert tel.wave_redo_rate == pytest.approx(redone.sum() / T)
+        assert obs_trace.enabled()
+        assert after - before == redone.sum()
 
     def test_waved_batch_kernel_route(self, workload, ivf_indices):
         """The Pallas batch kernel route agrees with the XLA waved route
